@@ -34,6 +34,12 @@ placement on two fresh hosts and asserts prefix-aware computes
 strictly fewer aggregate prefill tokens with identical outputs.
 Emits CSV rows plus results/BENCH_cluster.json.
 
+This is a CPU check of the transport, not a chip measurement: the
+parent process and every host subprocess use JAX, and only one process
+may hold a TPU chip, so the hosts are started with ``JAX_PLATFORMS=cpu``
+in their environment.  ``chip_smoke.py --four-chips`` runs replicas
+behind the router on chips, all in one process.
+
   PYTHONPATH=src python -m benchmarks.bench_cluster
   PYTHONPATH=src python -m benchmarks.run --only cluster
 """
@@ -96,7 +102,8 @@ class Host:
              "--max-len", str(MAX_LEN),
              "--host-tier-pages", str(HOST_TIER_PAGES),
              "--model-scale", str(MODEL_SCALE)],
-            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
         line = self.proc.stdout.readline().strip()
         assert line.startswith("LISTENING "), f"host {label}: {line!r}"
         self.port = int(line.split()[1])

@@ -168,8 +168,8 @@ def bench_kernel_grouping() -> Dict:
     pages = 1 + B * M
     rng = np.random.RandomState(7)
     q = jnp.asarray(rng.randn(B, H, hd), jnp.float32)
-    k_pages = jnp.asarray(rng.randn(pages, ps, K, hd), jnp.float32)
-    v_pages = jnp.asarray(rng.randn(pages, ps, K, hd), jnp.float32)
+    k_pages = jnp.asarray(rng.randn(pages, K, ps, hd), jnp.float32)
+    v_pages = jnp.asarray(rng.randn(pages, K, ps, hd), jnp.float32)
     bt = np.arange(1, 1 + B * M).reshape(B, M).astype(np.int32)
     lengths = np.array([3, 11, 25, 32], np.int32)    # mixed: short rows
     btj, lj = jnp.asarray(bt), jnp.asarray(lengths)  # skip pages

@@ -134,14 +134,24 @@ def eval_zoo(state) -> Dict[str, Any]:
     }
 
 
-def peak_hbm_bytes_per_s() -> float:
-    """Peak memory bandwidth (bytes/s) the roofline normalises achieved
-    bandwidth against.  ``REPRO_PEAK_HBM_GBPS`` overrides (set it to the
-    accelerator's datasheet number, e.g. 1640 for a v5p core); the
-    default 32 GB/s is a one-DDR5-channel-ish figure for the CPU CI
-    runner, so CI percentages are comparable run-to-run rather than
-    absolute truth."""
-    return float(os.environ.get("REPRO_PEAK_HBM_GBPS", "32")) * 1e9
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+#: TPU v5e ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
+#: 197 TFLOP/s bf16, 819 GB/s HBM.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_peaks(device_kind: str) -> Dict[str, float]:
+    """Peak FLOP/s and HBM bytes/s of one chip; a device without a
+    published entry is an error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}: add them to "
+                         f"benchmarks.common.DEVICE_PEAKS with their "
+                         f"source") from None
 
 
 def emit(name: str, us_per_call: float, derived: str):

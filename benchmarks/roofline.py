@@ -7,10 +7,12 @@ roofline terms, the dominant bottleneck, MODEL_FLOPS/HLO_FLOPS, and
 per-device memory.
 
 ``--paged`` runs every paged decode kernel variant (full / window /
-chunked / int8 / MLA v_dim, each grouped and per-head) and reports
-achieved bytes/s — analytic K/V bytes/token from the kernel's own
-grid accounting x measured steady-state tokens/s — against the peak
-from common.peak_hbm_bytes_per_s().  It also folds in the
+chunked / int8 / MLA v_dim, each grouped and per-head) and reports its
+analytic K/V bytes/token from the kernel's own grid accounting.  On a
+TPU it also times the compiled kernel and reports achieved bytes/s
+against the chip's published peak (common.device_peaks); off the chip
+it reports the byte counts only, since a CPU or interpret-mode time is
+not a device bandwidth.  It also folds in the
 hbm_bytes_per_token field of results/BENCH_paged_decode.json; under CI
 a missing bench artifact is a HARD FAILURE (nonzero exit), not a
 silent zero-row pass — run ``benchmarks.run --only paged`` first.
@@ -101,8 +103,8 @@ def _paged_inputs(variant: str, rng):
     kk = 1 if variant == "mla_vdim" else 2
     pages = 1 + B * M
     q = jnp.asarray(rng.randn(B, H, hd), jnp.float32)
-    k = rng.randn(pages, ps, kk, hd).astype(np.float32)
-    v = rng.randn(pages, ps, kk, hd).astype(np.float32)
+    k = rng.randn(pages, kk, ps, hd).astype(np.float32)
+    v = rng.randn(pages, kk, ps, hd).astype(np.float32)
     bt = np.arange(1, 1 + B * M).reshape(B, M).astype(np.int32)
     lengths = np.array([3, 11, 25, 32], np.int32)
     kw = {}
@@ -117,8 +119,8 @@ def _paged_inputs(variant: str, rng):
         vs_np = np.abs(v).max(axis=-1) / 127.0 + 1e-8
         k = np.clip(np.round(k / ks_np[..., None]), -127, 127)
         v = np.clip(np.round(v / vs_np[..., None]), -127, 127)
-        ks = jnp.asarray(ks_np, jnp.bfloat16)
-        vs = jnp.asarray(vs_np, jnp.bfloat16)
+        ks = jnp.asarray(ks_np[:, :, None, :], jnp.bfloat16)  # (P, K, 1, ps)
+        vs = jnp.asarray(vs_np[:, :, None, :], jnp.bfloat16)
         k = k.astype(np.int8)
         v = v.astype(np.int8)
     elif variant == "mla_vdim":
@@ -130,9 +132,9 @@ def _paged_inputs(variant: str, rng):
 
 
 def run_paged(ci: bool = None):
-    """Achieved vs peak HBM bytes/s per paged decode kernel variant,
-    from measured steady-state step time (jitted interpret-mode Pallas,
-    compile excluded) x the kernel's analytic bytes/token."""
+    """Analytic K/V bytes/token per paged decode kernel variant; on a
+    TPU also the compiled kernel's steady-state step time and achieved
+    bytes/s against the chip's peak."""
     import jax
     import numpy as np
     from repro.kernels import paged_attention as pk
@@ -140,46 +142,52 @@ def run_paged(ci: bool = None):
     if ci is None:
         ci = bool(os.environ.get("CI"))
     t_start = time.time()
-    peak = common.peak_hbm_bytes_per_s()
+    dev = jax.devices()[0]
+    on_chip = dev.platform == "tpu"
+    peak = (common.device_peaks(dev.device_kind)["hbm_bytes_per_s"]
+            if on_chip else None)
     rng = np.random.RandomState(3)
     variants = ("gqa_full", "gqa_window", "gqa_chunked", "gqa_int8",
                 "mla_vdim")
-    print("\n# Roofline — paged decode kernels, achieved vs peak HBM bytes/s")
-    print(f"# peak = {peak / 1e9:.1f} GB/s "
-          "(REPRO_PEAK_HBM_GBPS to override)")
-    print("variant,kernel,hbm_bytes_per_token,tokens_per_s,"
-          "achieved_MBps,peak_GBps,achieved_pct")
+    print(f"\n# Roofline — paged decode kernels on {dev.device_kind}")
+    if on_chip:
+        print(f"# peak = {peak / 1e9:.1f} GB/s (common.DEVICE_PEAKS)")
+        print("variant,kernel,hbm_bytes_per_token,tokens_per_s,"
+              "achieved_GBps,achieved_pct")
+    else:
+        print("# off the chip: byte counts only, no times")
+        print("variant,kernel,hbm_bytes_per_token")
     rows = []
     for variant in variants:
         kw, arrays, bt, lengths = _paged_inputs(variant, rng)
         q, k_pages, v_pages, btj, lj, ks, vs = arrays
         B = q.shape[0]
         for grouped in (True, False):
+            bpt = pk.decode_hbm_bytes(
+                k_pages, v_pages, bt, lengths, num_q_heads=q.shape[1],
+                grouped=grouped, window=kw.get("window"),
+                chunk=kw.get("chunk"), v_dim=kw.get("v_dim")) / B
+            row = {"variant": variant,
+                   "kernel": "grouped" if grouped else "per_head",
+                   "hbm_bytes_per_token": bpt}
+            rows.append(row)
+            if not on_chip:
+                print(f"{variant},{row['kernel']},{bpt:.0f}")
+                continue
             f = jax.jit(functools.partial(
-                pk.paged_attention, grouped=grouped, interpret=True,
-                k_scales=ks, v_scales=vs, **kw))
+                pk.paged_attention, grouped=grouped, k_scales=ks,
+                v_scales=vs, **kw))
             f(q, k_pages, v_pages, btj, lj).block_until_ready()  # compile
             best = float("inf")
             for _ in range(10):
                 t0 = time.perf_counter()
                 f(q, k_pages, v_pages, btj, lj).block_until_ready()
                 best = min(best, time.perf_counter() - t0)
-            bpt = pk.decode_hbm_bytes(
-                k_pages, v_pages, bt, lengths, num_q_heads=q.shape[1],
-                grouped=grouped, window=kw.get("window"),
-                chunk=kw.get("chunk"), v_dim=kw.get("v_dim")) / B
             tps = B / best
-            achieved = bpt * tps
-            rows.append({"variant": variant,
-                         "kernel": "grouped" if grouped else "per_head",
-                         "hbm_bytes_per_token": bpt,
-                         "tokens_per_s": tps,
-                         "achieved_bytes_per_s": achieved,
-                         "peak_bytes_per_s": peak,
-                         "achieved_pct": 100.0 * achieved / peak})
-            print(f"{variant},{rows[-1]['kernel']},{bpt:.0f},{tps:.0f},"
-                  f"{achieved / 1e6:.2f},{peak / 1e9:.1f},"
-                  f"{rows[-1]['achieved_pct']:.4f}")
+            row.update(tokens_per_s=tps, achieved_bytes_per_s=bpt * tps,
+                       achieved_pct=100.0 * bpt * tps / peak)
+            print(f"{variant},{row['kernel']},{bpt:.0f},{tps:.0f},"
+                  f"{bpt * tps / 1e9:.3f},{row['achieved_pct']:.4f}")
 
     # fold in the smoke bench's measured bytes/token — and refuse to
     # pass silently when the artifact is missing under CI
@@ -199,14 +207,15 @@ def run_paged(ci: bool = None):
         print(f"# roofline --paged: warning: {BENCH_ARTIFACT} missing "
               "(run benchmarks.run --only paged to populate it)")
 
-    best_row = max(rows, key=lambda r: r["achieved_pct"])
     us = (time.time() - t_start) * 1e6 / max(len(rows), 1)
-    common.emit(
-        "roofline_paged", us,
-        f"n={len(rows)} peak_GBps={peak / 1e9:.1f} "
-        f"best={best_row['variant']}/{best_row['kernel']}"
-        f"@{best_row['achieved_pct']:.4f}%")
-    payload = {"peak_bytes_per_s": peak, "rows": rows,
+    derived = f"n={len(rows)} device={dev.device_kind}"
+    if on_chip:
+        best_row = max(rows, key=lambda r: r["achieved_pct"])
+        derived += (f" best={best_row['variant']}/{best_row['kernel']}"
+                    f"@{best_row['achieved_pct']:.4f}%")
+    common.emit("roofline_paged", us, derived)
+    payload = {"device_kind": dev.device_kind, "peak_bytes_per_s": peak,
+               "rows": rows,
                "bench_hbm_bytes_per_token":
                    bench.get("hbm_bytes_per_token") if bench else None}
     common.emit_json("roofline_paged", payload)
@@ -216,9 +225,9 @@ def run_paged(ci: bool = None):
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--paged", action="store_true",
-                    help="measure the paged decode kernels' achieved vs "
-                         "peak HBM bandwidth instead of reading dry-run "
-                         "artifacts")
+                    help="report the paged decode kernels' HBM bytes/token "
+                         "(and, on a TPU, achieved vs peak bandwidth) "
+                         "instead of reading dry-run artifacts")
     ns = ap.parse_args()
     if ns.paged:
         run_paged()

@@ -68,6 +68,8 @@ def main() -> None:
                     help="export a Chrome trace JSON per serving benchmark "
                          "into this directory (Perfetto-loadable)")
     args, _ = ap.parse_known_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     if args.trace_dir:
         # benchmarks pick the destination up via common.trace_dest()

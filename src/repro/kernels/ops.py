@@ -1,18 +1,16 @@
 """Dispatching wrappers: Pallas on TPU, pure-jnp oracle elsewhere.
 
-``use_pallas()`` is True on real TPU backends; tests force the Pallas
-path on CPU with interpret=True (executes the kernel body in Python).
+``use_pallas()`` is True on real TPU backends.  Off the TPU the kernels
+run only when a test sets ``_FORCE``, and then in interpret mode
+(the kernel body executes in Python); on a TPU they always compile.
 The jnp fallbacks are not toys — they are the blocked/flash-equivalent
 implementations in repro.models.* whose HLO the dry-run analyses.
 """
 from __future__ import annotations
 
-import functools
-import os
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
@@ -20,17 +18,15 @@ from repro.kernels.mux_score import mux_score as _mux_pallas
 from repro.kernels.paged_attention import paged_attention as _paged_pallas
 from repro.kernels.selective_scan import selective_scan as _scan_pallas
 
-_FORCE = os.environ.get("REPRO_FORCE_PALLAS", "")  # "interpret" | "tpu" | ""
+_FORCE = False   # tests set True to run the kernels on the CPU (interpret)
 
 
 def use_pallas() -> bool:
-    if _FORCE:
-        return True
-    return jax.default_backend() == "tpu"
+    return _FORCE or jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
-    return _FORCE == "interpret" or jax.default_backend() != "tpu"
+    return jax.default_backend() != "tpu"
 
 
 def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
@@ -55,31 +51,18 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     v_dim: Optional[int] = None,
                     grouped: bool = True,
                     prefetch=None):
-    """Paged decode attention: Pallas kernel on TPU (KV-head-grouped
-    grid, block-table scalar prefetch, int8 dequant in-kernel), jnp
-    gather oracle elsewhere.  q: (B, H, hd) one token per row; lengths:
-    (B,).  ``prefetch`` is the combined (B, M+1) operand from
-    :func:`repro.kernels.paged_attention.decode_prefetch`, built once
-    per decode step and shared across layers (ignored by the oracle,
-    which reads block_tables/lengths directly)."""
-    if use_pallas():
-        return _paged_pallas(q, k_pages, v_pages, block_tables, lengths,
-                             window=window, chunk=chunk, logit_cap=logit_cap,
-                             scale=scale, k_scales=k_scales,
-                             v_scales=v_scales, v_dim=v_dim,
-                             grouped=grouped, prefetch=prefetch,
-                             interpret=_interpret())
-    # oracle fallback (the models' own jnp path is
-    # attention.paged_decode_attention; this keeps the dispatcher
-    # usable standalone): dequantize slabs, then full-materialisation
-    if k_pages.dtype == jnp.int8:
-        k_pages = k_pages.astype(jnp.bfloat16) * k_scales[..., None]
-        v_pages = v_pages.astype(jnp.bfloat16) * v_scales[..., None]
-    if v_dim is not None:
-        v_pages = v_pages[..., :v_dim]
-    return ref.paged_attention_ref(q, k_pages, v_pages, block_tables,
-                                   lengths, window=window, chunk=chunk,
-                                   scale=scale, logit_cap=logit_cap)
+    """Paged decode attention through the Pallas kernel (KV-head-grouped
+    grid, block-table scalar prefetch, int8 dequant in-kernel), for
+    callers that checked ``use_pallas()``; their jnp path is the gather
+    in attention.paged_decode_attention.  q: (B, H, hd) one token per
+    row; lengths: (B,).  ``prefetch`` is the combined (B, M+1) operand
+    from :func:`repro.kernels.paged_attention.decode_prefetch`, built
+    once per decode step and shared across layers."""
+    return _paged_pallas(q, k_pages, v_pages, block_tables, lengths,
+                         window=window, chunk=chunk, logit_cap=logit_cap,
+                         scale=scale, k_scales=k_scales, v_scales=v_scales,
+                         v_dim=v_dim, grouped=grouped, prefetch=prefetch,
+                         interpret=_interpret())
 
 
 def selective_scan(x, dt, b_mat, c_mat, a_mat, d_vec):

@@ -2,12 +2,14 @@
 sliding-window / chunked masks, logit soft-capping, int8 pages).
 
 One query token per batch row attends over that row's KV pages.  Pages
-are pool-wide slabs (num_pages, page_size, K, hd) shared by every
-request; each row's ordered page list arrives as a block-table row that
-is **scalar-prefetched** (pltpu.PrefetchScalarGridSpec) so the BlockSpec
-index_map can steer the K/V DMA to the right page before the kernel
-body runs — the gather never materialises a contiguous per-row KV copy
-in HBM.
+are pool-wide head-major slabs (num_pages, K, page_size, hd) shared by
+every request, so one (page, kv head) block is a whole (page_size, hd)
+tile, as the TPU's (8, 128) tiling requires of a block's two minor
+dimensions.  Each row's ordered page list arrives as a block-table row
+that is **scalar-prefetched** (pltpu.PrefetchScalarGridSpec) so the
+BlockSpec index_map can steer the K/V DMA to the right page before the
+kernel body runs — the gather never materialises a contiguous per-row
+KV copy in HBM.
 
 Grid: (batch, kv_heads, num_pages_per_row) with a (g, hd) query block,
 where g = q_heads // kv_heads is the GQA group size.  Each K/V page is
@@ -24,8 +26,12 @@ length — short rows in a mixed-length decode batch do proportionally
 less work, which is the point of paging.
 
 When the pool stores int8, per-(slot, head) bf16 scales ride along as
-two more page slabs and K/V are dequantized in-kernel after the DMA —
-HBM traffic stays at the quantized width.
+two more page slabs of shape (num_pages, K, 1, page_size) — one lane
+row per (page, kv head).  A scale is constant along a slot's features,
+so it factors out of both matmuls and is applied along the key axis of
+the (G, page_size) score / probability tiles: q·(k∘s_k) = (q·k)∘s_k and
+p·(v∘s_v) = (p∘s_v)·v.  No lane-to-sublane reshape of the scale row is
+needed, and HBM traffic stays at the quantized width.
 
 :func:`decode_prefetch` packs block tables and lengths into ONE
 (B, M+1) int32 scalar operand that the caller builds once per decode
@@ -89,13 +95,12 @@ def _paged_attn_kernel(*refs, scale: float, window: Optional[int],
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale         # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (ps, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)           # (ps, vd)
-        if quantized:
-            k = k * ks_ref[0, :, 0][:, None].astype(jnp.float32)
-            v = v * vs_ref[0, :, 0][:, None].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                 # (ps, hd)
+        v = v_ref[0, 0].astype(jnp.float32)                 # (ps, vd)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
+        if quantized:                                       # (1, ps) rows
+            sc = sc * ks_ref[0, 0].astype(jnp.float32)
         if logit_cap is not None:
             sc = jnp.tanh(sc / logit_cap) * logit_cap
         kv_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
@@ -111,8 +116,9 @@ def _paged_attn_kernel(*refs, scale: float, window: Optional[int],
         p = jnp.exp(sc - m_new[:, None])
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
+        pv = p * vs_ref[0, 0].astype(jnp.float32) if quantized else p
         acc_scr[...] = (acc_scr[...] * corr[:, None]
-                        + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                        + jax.lax.dot_general(pv, v, (((1,), (0,)), ((), ())),
                                               preferred_element_type=jnp.float32))
         m_scr[...] = m_new
 
@@ -154,8 +160,8 @@ def decode_hbm_bytes(k_pages, v_pages, block_tables, lengths, *,
     grouped=False counts one per (row, q_head, live page) — the exact
     g-fold difference the re-grid removes.
     """
-    ps = int(k_pages.shape[1])
-    kk = int(k_pages.shape[2])
+    kk = int(k_pages.shape[1])
+    ps = int(k_pages.shape[2])
     hd = int(k_pages.shape[3])
     vd = int(v_dim) if v_dim is not None else int(v_pages.shape[-1])
     if quantized is None:
@@ -190,9 +196,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     grouped: bool = True,
                     prefetch=None,
                     interpret: bool = False):
-    """q: (B, H, hd); k_pages/v_pages: (P, page_size, K, hd|vd);
+    """q: (B, H, hd); k_pages/v_pages: (P, K, page_size, hd|vd);
     block_tables: (B, M) int32; lengths: (B,) int32 visible tokens per
-    row (query at lengths - 1).  k_scales/v_scales: (P, page_size, K)
+    row (query at lengths - 1).  k_scales/v_scales: (P, K, 1, page_size)
     bf16 when the pages are int8.  ``v_dim`` reads only the leading
     v_dim features of each v page — with v_pages=k_pages that serves
     absorbed-MLA decode, where v is the latent's first kv_lora features
@@ -208,7 +214,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, hd = q.shape
-    num_pages, ps, kk, _ = k_pages.shape
+    num_pages, kk, ps, _ = k_pages.shape
     vd = v_dim if v_dim is not None else v_pages.shape[-1]
     m = block_tables.shape[1]
     g = h // kk
@@ -239,10 +245,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
             return (b_, h_, 0, 0)
 
         def kv_idx(b_, h_, i, pf):
-            return (pf[b_, i], 0, kv_head(h_), 0)
-
-        def sc_idx(b_, h_, i, pf):
-            return (pf[b_, i], 0, kv_head(h_))
+            return (pf[b_, i], kv_head(h_), 0, 0)
     else:
         length_col = None
         nsp = 2
@@ -252,10 +255,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
             return (b_, h_, 0, 0)
 
         def kv_idx(b_, h_, i, bt, ln):
-            return (bt[b_, i], 0, kv_head(h_), 0)
-
-        def sc_idx(b_, h_, i, bt, ln):
-            return (bt[b_, i], 0, kv_head(h_))
+            return (bt[b_, i], kv_head(h_), 0, 0)
 
     kernel = functools.partial(
         _paged_attn_kernel, scale=scale_, window=window, chunk=chunk,
@@ -263,16 +263,18 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         length_col=length_col)
 
     # index maps see the grid indices then the scalar-prefetch ref(s);
-    # the page id for (row b, step i) steers the K/V (and scale) DMAs
+    # the page id for (row b, step i) steers the K/V (and scale) DMAs.
+    # Every block's two minor dims are (ps, hd|vd) or (1, ps): whole
+    # tiles of the head-major pool, as the TPU's tiling requires
     in_specs = [
         pl.BlockSpec((1, 1, G, hd), q_idx),
-        pl.BlockSpec((1, ps, 1, hd), kv_idx),
-        pl.BlockSpec((1, ps, 1, vd), kv_idx),
+        pl.BlockSpec((1, 1, ps, hd), kv_idx),
+        pl.BlockSpec((1, 1, ps, vd), kv_idx),
     ]
     args = [qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, ps, 1), sc_idx),
-                     pl.BlockSpec((1, ps, 1), sc_idx)]
+        in_specs += [pl.BlockSpec((1, 1, 1, ps), kv_idx),
+                     pl.BlockSpec((1, 1, 1, ps), kv_idx)]
         args += [k_scales, v_scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

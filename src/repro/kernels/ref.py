@@ -58,17 +58,22 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     by per-row length, full-materialisation softmax.
 
     q: (B, H, hd) one query token per row; k_pages/v_pages:
-    (P, page_size, K, hd|vd) pool-wide page slabs; block_tables: (B, M)
-    int32 page ids ordered by logical position; lengths: (B,) visible
-    tokens per row (the query sits at lengths - 1).
+    (P, K, page_size, hd|vd) head-major pool-wide page slabs;
+    block_tables: (B, M) int32 page ids ordered by logical position;
+    lengths: (B,) visible tokens per row (the query sits at lengths - 1).
     Returns (B, H, vd) in q.dtype.
     """
     b, h, hd = q.shape
-    ps, kk = k_pages.shape[1], k_pages.shape[2]
+    kk = k_pages.shape[1]
     g = h // kk
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    k = k_pages[block_tables].reshape(b, -1, kk, k_pages.shape[-1])
-    v = v_pages[block_tables].reshape(b, -1, kk, v_pages.shape[-1])
+
+    def gather(pages):                      # (B, M, K, ps, d) -> (B, T, K, d)
+        x = jnp.swapaxes(pages[block_tables], 2, 3)
+        return x.reshape(b, -1, kk, pages.shape[-1])
+
+    k = gather(k_pages)
+    v = gather(v_pages)
     t = k.shape[1]
     qr = (q * scale).reshape(b, kk, g, hd)
     sc = jnp.einsum("bkgd,btkd->bkgt", qr, k,

@@ -14,6 +14,7 @@ import argparse
 import jax
 
 from repro.configs import INPUT_SHAPES, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim import adamw
 from repro.sharding.partition import resolve, train_rules
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         cfg = get_smoke_config(args.arch)

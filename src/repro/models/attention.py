@@ -16,10 +16,11 @@ Decode uses one of two cache layouts behind the same masking core
 (``masked_decode_attention``):
   * ring buffer of capacity = attention span, one slab per batch slot;
     each slot remembers the absolute position it holds (``pos_buf``)
-  * paged pool — (num_pages, page_size) slabs shared by all requests,
-    addressed through per-row block tables, with per-row query
-    positions so a decode batch can mix requests at different lengths
-    (token-level continuous batching; see repro.serving.kv_cache).
+  * paged pool — head-major (num_pages, K, page_size, hd) slabs shared
+    by all requests, addressed through per-row block tables, with
+    per-row query positions so a decode batch can mix requests at
+    different lengths (token-level continuous batching; see
+    repro.serving.kv_cache).
 """
 from __future__ import annotations
 
@@ -503,15 +504,18 @@ def decode_attention(q, cache: Params, pos, *, window: Optional[int] = None,
 # Paged KV cache + decode attention
 # ---------------------------------------------------------------------------
 #
-# Pages are pool-wide, NOT per batch row: cache["k"] is
-# (num_pages, page_size, K, hd) and a request owns an ordered list of
-# pages recorded in its block-table row.  Logical token j of a request
-# lives in page block_table[j // page_size] at slot j % page_size, so a
-# gathered view is position-ordered and the mask is simply
-# kv_pos = arange(T) against the per-row query position — the same
-# masked_decode_attention core the ring path uses.  Page 0 is reserved
-# as a scratch page: padding block-table entries and inactive batch
-# rows point at it, and everything they write there is masked out.
+# Pages are pool-wide, NOT per batch row: cache["k"] is head-major,
+# (num_pages, K, page_size, hd), so the paged kernel's block for one
+# (page, kv head) is a whole (page_size, hd) tile.  A request owns an
+# ordered list of pages recorded in its block-table row.  Logical
+# token j of a request lives in page block_table[j // page_size] at
+# slot j % page_size, so a gathered view is position-ordered and the
+# mask is simply kv_pos = arange(T) against the per-row query position
+# — the same masked_decode_attention core the ring path uses.  Page 0
+# is reserved as a scratch page: padding block-table entries and
+# inactive batch rows point at it, and everything they write there is
+# masked out.  int8 pools keep per-(slot, head) scales as
+# (num_pages, K, 1, page_size): one lane row per (page, kv head).
 
 SCRATCH_PAGE = 0
 
@@ -525,15 +529,31 @@ def init_paged_kv_cache(num_pages: int, page_size: int, num_kv_heads: int,
     if isinstance(dtype, str):
         dtype = jnp.dtype(dtype)
     cache = {
-        "k": jnp.zeros((num_pages, page_size, num_kv_heads, head_dim), dtype),
-        "v": jnp.zeros((num_pages, page_size, num_kv_heads, v_hd), dtype),
+        "k": jnp.zeros((num_pages, num_kv_heads, page_size, head_dim), dtype),
+        "v": jnp.zeros((num_pages, num_kv_heads, page_size, v_hd), dtype),
     }
     if dtype == jnp.int8:
-        cache["k_scale"] = jnp.zeros((num_pages, page_size, num_kv_heads),
-                                     jnp.bfloat16)
-        cache["v_scale"] = jnp.zeros((num_pages, page_size, num_kv_heads),
-                                     jnp.bfloat16)
+        for name in ("k_scale", "v_scale"):
+            cache[name] = jnp.zeros((num_pages, num_kv_heads, 1, page_size),
+                                    jnp.bfloat16)
     return cache
+
+
+def _paged_write(cache: Params, k, v, page, slot) -> Params:
+    """Scatter token K/V (..., K, hd) into the pool at (page, slot)
+    index arrays of the same leading shape, quantizing for int8 pools.
+    The page and slot indices sit on either side of the head axis, so
+    the indexed view is (..., K, hd) — the tokens' own layout."""
+    out = dict(cache)
+    if cache["k"].dtype == jnp.int8:
+        kq, ks = _quantize(k, jnp.int8)
+        vq, vs = _quantize(v, jnp.int8)
+        out["k_scale"] = cache["k_scale"].at[page, :, 0, slot].set(ks)
+        out["v_scale"] = cache["v_scale"].at[page, :, 0, slot].set(vs)
+        k, v = kq, vq
+    out["k"] = cache["k"].at[page, :, slot].set(k.astype(cache["k"].dtype))
+    out["v"] = cache["v"].at[page, :, slot].set(v.astype(cache["v"].dtype))
+    return out
 
 
 def paged_cache_insert(cache: Params, k_new, v_new, block_tables,
@@ -541,23 +561,11 @@ def paged_cache_insert(cache: Params, k_new, v_new, block_tables,
     """Insert one token per row: k/v (B, 1, K, hd) at per-row position
     ``pos`` (B,) via ``block_tables`` (B, M).  Inactive rows should
     point at SCRATCH_PAGE; colliding scratch writes are harmless."""
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     pos = jnp.asarray(pos, jnp.int32).reshape((-1,))
     page = jnp.take_along_axis(block_tables, (pos // ps)[:, None],
                                axis=1)[:, 0]                    # (B,)
-    slot = pos % ps
-    out = dict(cache)
-    if cache["k"].dtype == jnp.int8:
-        kq, ks = _quantize(k_new, jnp.int8)
-        vq, vs = _quantize(v_new, jnp.int8)
-        out["k_scale"] = cache["k_scale"].at[page, slot].set(ks[:, 0])
-        out["v_scale"] = cache["v_scale"].at[page, slot].set(vs[:, 0])
-        k_new, v_new = kq, vq
-    out["k"] = cache["k"].at[page, slot].set(
-        k_new[:, 0].astype(cache["k"].dtype))
-    out["v"] = cache["v"].at[page, slot].set(
-        v_new[:, 0].astype(cache["v"].dtype))
-    return out
+    return _paged_write(cache, k_new[:, 0], v_new[:, 0], page, pos % ps)
 
 
 def paged_cache_prefill(cache: Params, k, v, block_tables,
@@ -573,7 +581,7 @@ def paged_cache_prefill(cache: Params, k, v, block_tables,
     shared pages that already hold their K/V.  Positions whose page
     index falls past the block-table width also land on scratch
     (right-padding of a page-rounded tail near max_len)."""
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     s = k.shape[1]
     m = block_tables.shape[1]
     positions = (jnp.asarray(start, jnp.int32).reshape((-1, 1))
@@ -586,21 +594,13 @@ def paged_cache_prefill(cache: Params, k, v, block_tables,
         ins = jnp.asarray(insert_from, jnp.int32).reshape((-1, 1))
         page = jnp.where(positions >= ins, page, SCRATCH_PAGE)
     slot = jnp.broadcast_to(positions % ps, page.shape)
-    out = dict(cache)
-    if cache["k"].dtype == jnp.int8:
-        kq, ks = _quantize(k, jnp.int8)
-        vq, vs = _quantize(v, jnp.int8)
-        out["k_scale"] = cache["k_scale"].at[page, slot].set(ks)
-        out["v_scale"] = cache["v_scale"].at[page, slot].set(vs)
-        k, v = kq, vq
-    out["k"] = cache["k"].at[page, slot].set(k.astype(cache["k"].dtype))
-    out["v"] = cache["v"].at[page, slot].set(v.astype(cache["v"].dtype))
-    return out
+    return _paged_write(cache, k, v, page, slot)
 
 
 def gather_pages(pages, block_tables):
-    """pages (P, ps, ...) gathered to a per-row view (B, M * ps, ...)."""
-    g = pages[block_tables]                       # (B, M, ps, ...)
+    """Head-major pages (P, K, ps, ...) gathered to a position-ordered
+    per-row view (B, M * ps, K, ...)."""
+    g = jnp.swapaxes(pages[block_tables], 2, 3)   # (B, M, ps, K, ...)
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
@@ -611,10 +611,11 @@ def paged_gather_kv(cache: Params, block_tables):
     k = gather_pages(cache["k"], block_tables)
     v = gather_pages(cache["v"], block_tables)
     if k.dtype == jnp.int8:
-        k = k.astype(jnp.bfloat16) * gather_pages(cache["k_scale"],
-                                                  block_tables)[..., None]
-        v = v.astype(jnp.bfloat16) * gather_pages(cache["v_scale"],
-                                                  block_tables)[..., None]
+        # scales (P, K, 1, ps) gather like (P, K, ps, 1) pages
+        def scales(name):
+            return gather_pages(jnp.swapaxes(cache[name], 2, 3), block_tables)
+        k = k.astype(jnp.bfloat16) * scales("k_scale")
+        v = v.astype(jnp.bfloat16) * scales("v_scale")
     return k, v
 
 

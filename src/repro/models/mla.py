@@ -138,7 +138,7 @@ def mla_decode(params: Params, x, cache: Params, pos, *, num_heads: int,
                rope_theta: float, block_tables=None, prefetch=None):
     """Absorbed single-token decode.  cache['k']: (B, cap, 1, kv_lora+d_rope)
     (ring), or with ``block_tables`` (B, M) a paged latent pool
-    (P, page_size, 1, kv_lora+d_rope) with per-row positions ``pos`` (B,).
+    (P, 1, page_size, kv_lora+d_rope) with per-row positions ``pos`` (B,).
 
     Returns (out (B,1,D), new_cache).
     """

@@ -29,14 +29,13 @@ import os
 import signal
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")   # noqa: E402 — before jax
-
-from repro.configs.base import ModelConfig                      # noqa: E402
-from repro.models import transformer as tf                      # noqa: E402
-from repro.serving.backend import InProcessBackend              # noqa: E402
-from repro.serving.cluster.transport import SocketBackendServer  # noqa: E402
-from repro.serving.engine import Engine, ServeConfig            # noqa: E402
-from repro.serving.observability import Tracer                  # noqa: E402
+from repro.configs.base import ModelConfig
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import transformer as tf
+from repro.serving.backend import InProcessBackend
+from repro.serving.cluster.transport import SocketBackendServer
+from repro.serving.engine import Engine, ServeConfig
+from repro.serving.observability import Tracer
 
 
 def tiny_model_config(scale: int = 1) -> ModelConfig:
@@ -140,6 +139,7 @@ async def _amain(args: argparse.Namespace) -> int:
 
 def main(argv=None) -> int:
     args = _parser().parse_args(argv)
+    enable_compile_cache()
     try:
         return asyncio.run(_amain(args))
     except KeyboardInterrupt:

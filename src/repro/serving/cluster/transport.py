@@ -224,10 +224,9 @@ class SocketBackendServer:
     async def close(self) -> None:
         """Stop listening, kill sweeps, reclaim every session's
         sequences, and stop the inner backend."""
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        server, self._server = self._server, None
+        if server is not None:
+            server.close()                # stop accepting connections
         for sess in self._sessions.values():
             if sess.sweep_task is not None:
                 sess.sweep_task.cancel()
@@ -237,6 +236,10 @@ class SocketBackendServer:
                 await _drain_close(sess.writer)
             sess.server.reclaim()
         self._sessions.clear()
+        if server is not None:
+            # since Python 3.12 this also waits for every open
+            # connection to end, so it comes after the pipes are closed
+            await server.wait_closed()
         await self.inner.stop()
 
     # ---- connection handling ------------------------------------------
@@ -548,10 +551,12 @@ class SocketClientBackend(ModelBackend):
     async def stop(self) -> None:
         self._stopping = True
         # let release acks land: shutdown reclaims leftovers anyway but
-        # an abandoned retry task dies noisily with the loop
-        while self._release_tasks:
-            await asyncio.gather(*list(self._release_tasks),
-                                 return_exceptions=True)
+        # an abandoned retry task dies noisily with the loop.  Wait on
+        # the unfinished tasks only: a finished one stays in the set
+        # until its discard callback runs, and gathering finished tasks
+        # completes without yielding, so that callback would never run
+        while pending := [t for t in self._release_tasks if not t.done()]:
+            await asyncio.gather(*pending, return_exceptions=True)
         if self.connected:
             try:
                 await asyncio.wait_for(self._call("shutdown"),

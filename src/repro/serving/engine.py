@@ -350,6 +350,23 @@ class Engine:
         init_paged) — part of the engine's paged-serving contract."""
         return self._decode_batch
 
+    def devices(self) -> set:
+        """Devices holding this engine's parameters and paged pool."""
+        leaves = jax.tree.leaves((self.params, self._paged_caches))
+        return {d for x in leaves for d in x.devices()}
+
+    def lower_paged_decode(self):
+        """The paged decode step lowered at this engine's serving shapes
+        (its pool, ``decode_batch`` rows, the block-table width);
+        ``.compile().as_text()`` is the program every decode step runs."""
+        if self.pool is None:
+            raise RuntimeError("no paged KV pool: call init_paged() first")
+        cap = self._decode_batch
+        return self._paged_decode.lower(
+            self.params, jnp.zeros((cap, 1), jnp.int32), self._paged_caches,
+            jnp.zeros((cap, self._max_pages), jnp.int32),
+            jnp.zeros((cap,), jnp.int32))
+
     # ---- window/chunked span reclaim ----------------------------------
     def _banded_spans(self) -> Optional[List[Tuple[str, int]]]:
         """(kind, span) per pattern layer when EVERY layer is banded

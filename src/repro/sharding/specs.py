@@ -65,6 +65,12 @@ _CACHE_TABLE: Tuple[Tuple[str, Tuple[Optional[str], ...]], ...] = (
     (r"/h$", ("batch", "d_inner", None)),
 )
 
+# the paged pool is shared by every request: no batch or sequence axis,
+# pages (P, K, ps, hd) and int8 scales (P, K, 1, ps) split over kv heads
+_PAGED_CACHE_TABLE: Tuple[Tuple[str, Tuple[Optional[str], ...]], ...] = (
+    (r"/(k|v|k_scale|v_scale)$", (None, "kv_heads", None, None)),
+)
+
 
 def _leaf_path(path) -> str:
     parts = []
@@ -121,13 +127,16 @@ def param_specs(params: Any, rules: Dict[str, MeshAxis], mesh=None) -> Any:
     return jax.tree_util.tree_map_with_path(f, params)
 
 
-def cache_specs(caches: Any, rules: Dict[str, MeshAxis], mesh=None) -> Any:
+def cache_specs(caches: Any, rules: Dict[str, MeshAxis], mesh=None, *,
+                paged: bool = False) -> Any:
+    """PartitionSpec pytree for ring caches, or for a paged pool
+    (``paged=True``) whose leaves carry the same names."""
     if mesh is not None:
         rules = resolve(rules, mesh)
+    table = _PAGED_CACHE_TABLE if paged else _CACHE_TABLE
 
     def f(path, leaf):
-        return _spec_for(_leaf_path(path), leaf.shape, _CACHE_TABLE, rules,
-                         mesh)
+        return _spec_for(_leaf_path(path), leaf.shape, table, rules, mesh)
 
     return jax.tree_util.tree_map_with_path(f, caches)
 

@@ -53,8 +53,8 @@ def test_paged_attention_sweep(b, h, k, hd, vd, pages, ps, m, window, chunk,
     lengths, block-table indirection, window/chunk masks."""
     kq, kk, kv, kt = jax.random.split(KEY, 4)
     q = jax.random.normal(kq, (b, h, hd))
-    k_pages = jax.random.normal(kk, (pages, ps, k, hd))
-    v_pages = jax.random.normal(kv, (pages, ps, k, vd))
+    k_pages = jax.random.normal(kk, (pages, k, ps, hd))
+    v_pages = jax.random.normal(kv, (pages, k, ps, vd))
     # each row gets m distinct pages drawn from 1..pages-1 (0 = scratch)
     perm = np.stack([np.random.RandomState(i).permutation(pages - 1)[:m] + 1
                      for i in range(b)])
@@ -74,7 +74,7 @@ def test_paged_attention_v_dim_is_k_slice():
     b, h, hd, ps, m, pages, vdim = 2, 4, 24, 4, 3, 8, 16
     kq, kk = jax.random.split(KEY)
     q = jax.random.normal(kq, (b, h, hd))
-    k_pages = jax.random.normal(kk, (pages, ps, 1, hd))     # MQA latent
+    k_pages = jax.random.normal(kk, (pages, 1, ps, hd))     # MQA latent
     bt = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
     lengths = jnp.asarray([m * ps, 5], jnp.int32)
     out = paged_attention(q, k_pages, k_pages, bt, lengths, v_dim=vdim,
@@ -111,10 +111,12 @@ def test_paged_attention_int8_dequant_in_kernel():
 
 
 def _quantize_pages(pages):
-    """Per-(slot, head) symmetric int8 + bf16 scales, like the pool's."""
+    """Per-(slot, head) symmetric int8 + bf16 scales, like the pool's:
+    pages (P, K, ps, hd) -> scales (P, K, 1, ps)."""
     sc = np.abs(np.asarray(pages)).max(axis=-1) / 127.0 + 1e-8
     qp = np.clip(np.round(np.asarray(pages) / sc[..., None]), -127, 127)
-    return jnp.asarray(qp, jnp.int8), jnp.asarray(sc, jnp.bfloat16)
+    return (jnp.asarray(qp, jnp.int8),
+            jnp.asarray(sc[:, :, None, :], jnp.bfloat16))
 
 
 _GROUP_VARIANTS = {
@@ -139,9 +141,9 @@ def test_paged_grouped_token_identical_to_per_head(g, variant, qtag):
     kw = dict(_GROUP_VARIANTS[variant])
     kq, kp, kv = jax.random.split(jax.random.fold_in(KEY, g), 3)
     q = jax.random.normal(kq, (b, h, hd), jnp.bfloat16)
-    k_pages = jax.random.normal(kp, (pages, ps, kk, hd), jnp.bfloat16)
+    k_pages = jax.random.normal(kp, (pages, kk, ps, hd), jnp.bfloat16)
     v_pages = (k_pages if variant == "mla_vdim"
-               else jax.random.normal(kv, (pages, ps, kk, hd), jnp.bfloat16))
+               else jax.random.normal(kv, (pages, kk, ps, hd), jnp.bfloat16))
     ks = vs = None
     if qtag == "int8":
         k_pages, ks = _quantize_pages(k_pages)
@@ -172,7 +174,7 @@ def test_paged_zero_length_rows_are_exact_zeros():
     pages = 1 + b * m
     kq, kp = jax.random.split(KEY)
     q = jax.random.normal(kq, (b, h, hd))
-    k_pages = jax.random.normal(kp, (pages, ps, kk, hd))
+    k_pages = jax.random.normal(kp, (pages, kk, ps, hd))
     bt = jnp.asarray(np.arange(1, pages).reshape(b, m), jnp.int32)
     lengths = jnp.asarray([0, 7, 0], jnp.int32)
     for grouped in (True, False):
@@ -196,8 +198,8 @@ def test_paged_combined_prefetch_matches_separate_operands():
     pages = 1 + b * m
     kq, kp, kv = jax.random.split(KEY, 3)
     q = jax.random.normal(kq, (b, h, hd))
-    k_pages = jax.random.normal(kp, (pages, ps, kk, hd))
-    v_pages = jax.random.normal(kv, (pages, ps, kk, hd))
+    k_pages = jax.random.normal(kp, (pages, kk, ps, hd))
+    v_pages = jax.random.normal(kv, (pages, kk, ps, hd))
     bt = jnp.asarray(np.arange(1, pages).reshape(b, m), jnp.int32)
     lengths = jnp.asarray([13, 32], jnp.int32)
     pf = decode_prefetch(bt, lengths)
@@ -217,7 +219,7 @@ def test_decode_hbm_bytes_accounting():
     from repro.kernels.paged_attention import decode_hbm_bytes
     ps, kk, hd, m = 8, 2, 16, 4
     h = 8
-    k_pages = jnp.zeros((9, ps, kk, hd), jnp.float32)
+    k_pages = jnp.zeros((9, kk, ps, hd), jnp.float32)
     bt = np.arange(1, 9).reshape(2, m)
     full = decode_hbm_bytes(k_pages, k_pages, bt, [32, 32], num_q_heads=h)
     # 2 rows x 4 live pages x 2 kv heads x (ps*hd*4 k + ps*hd*4 v)
@@ -230,7 +232,7 @@ def test_decode_hbm_bytes_accounting():
     per_head = decode_hbm_bytes(k_pages, k_pages, bt, [32, 32],
                                 num_q_heads=h, grouped=False)
     assert per_head == full * (h // kk)
-    q8 = jnp.zeros((9, ps, kk, hd), jnp.int8)
+    q8 = jnp.zeros((9, kk, ps, hd), jnp.int8)
     quant = decode_hbm_bytes(q8, q8, bt, [32, 32], num_q_heads=h)
     assert quant == 2 * 4 * kk * (ps * hd * 1 * 2 + 2 * ps * 2)
     vd = decode_hbm_bytes(k_pages, k_pages, bt, [32, 32], num_q_heads=h,

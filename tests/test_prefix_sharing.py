@@ -168,7 +168,7 @@ def test_parity_under_forced_pallas_interpret(monkeypatch):
                                         seed=13)
     off = make_engine(cfg, params, sharing=False)
     on = make_engine(cfg, params, sharing=True)
-    monkeypatch.setattr(kops, "_FORCE", "interpret")
+    monkeypatch.setattr(kops, "_FORCE", True)
     ref_a = off.generate_paged(pa, max_new_tokens=4)["tokens"]
     ref_b = off.generate_paged(pb, max_new_tokens=4)["tokens"]
     sa = on.prefill_into_pages(pa, max_new_tokens=4)

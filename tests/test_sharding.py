@@ -103,3 +103,20 @@ def test_cache_specs_shape_alignment():
     specs = jax.tree.leaves(spec_tree, is_leaf=lambda x: isinstance(x, P))
     for (path, leaf), spec in zip(flat, specs):
         assert len(spec) <= leaf.ndim, (path, leaf.shape, spec)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+def test_paged_cache_specs_split_kv_heads(dtype):
+    """A paged pool leaf (G, P, K, ps, hd) — or its int8 scales
+    (G, P, K, 1, ps) — shards only its kv-head axis: the pool has no
+    batch or sequence axis for the ring rules to land on."""
+    cfg = get_smoke_config("olmo-1b")
+    caches = tf.abstract_caches(cfg, 0, 0, dtype, num_pages=5, page_size=8)
+    rules = decode_rules(True, True)
+    spec_tree = sp.cache_specs(caches, rules, paged=True)
+    specs = jax.tree.leaves(spec_tree, is_leaf=lambda x: isinstance(x, P))
+    leaves = jax.tree.leaves(caches)
+    assert len(specs) == len(leaves) == (4 if dtype == "int8" else 2)
+    for leaf, spec in zip(leaves, specs):
+        assert leaf.shape[2] == cfg.num_kv_heads
+        assert tuple(spec) == (None, None, rules["kv_heads"], None, None)

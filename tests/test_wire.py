@@ -14,6 +14,7 @@ separate from the happy-path cluster tests:
   (hypothesis fuzz, skipped when hypothesis is not installed).
 """
 import asyncio
+import threading
 
 import numpy as np
 import pytest
@@ -202,6 +203,26 @@ def test_wrong_secret_refused_before_any_op():
         await srv.close()
 
     asyncio.run(main())
+
+
+def test_stop_returns_while_a_finished_release_task_is_listed():
+    """A release task that finished in the loop pass where stop() began
+    is still in the client's task set until its discard callback runs.
+    stop() must not spin on it: gathering finished tasks never yields
+    to the loop, so that callback would never run.  The client runs in
+    a thread so a spinning stop() fails the test instead of hanging it."""
+    stopped = threading.Event()
+
+    async def main():
+        cli = SocketClientBackend("127.0.0.1", 1)
+        finished = asyncio.ensure_future(asyncio.sleep(0))
+        await finished
+        cli._release_tasks.add(finished)   # listed, no discard callback
+        await cli.stop()
+        stopped.set()
+
+    threading.Thread(target=asyncio.run, args=(main(),), daemon=True).start()
+    assert stopped.wait(timeout=10.0), "stop() spun on a finished task"
 
 
 # ---------------------------------------------------------------------------
