@@ -177,7 +177,9 @@ def run() -> None:
     assert len(cow_evs) == shared["cow_copies"], (
         f"{len(cow_evs)} cow instants vs {shared['cow_copies']} copies")
     assert all(e[6].get("fused") is True for e in cow_evs)
-    assert not [e for e in evs if "copy_page" in e[2]], (
+    # executor calls are all named repro.executor.run, their op in args
+    assert not [e for e in evs if "copy_page" in e[2]
+                or "copy_page" in str((e[6] or {}).get("op", ""))], (
         "standalone page-copy dispatch found: COW is not fused")
 
     flops_saved = 1.0 - (shared["prefill_flops"]

@@ -247,19 +247,18 @@ class _ExecutorMixin:
         t_submit = time.monotonic()
 
         def wrapped():
-            t_start = time.monotonic()
-            self._note_queue_wait(t_start - t_submit)
-            try:
+            queued = time.monotonic() - t_submit
+            self._note_queue_wait(queued)
+            tracer = self._tracer
+            if not tracer.enabled:
                 return fn(*args)
-            finally:
-                tracer = self._tracer
-                if tracer.enabled:
-                    # executor-occupancy span: runs on the executor
-                    # thread itself, which the lock-free ring allows
-                    tracer.span(op or getattr(fn, "__name__", "call"),
-                                backend_track(self.name, executor),
-                                t_start, time.monotonic(),
-                                {"queued_ms": (t_start - t_submit) * 1e3})
+            # executor-occupancy span: runs on the executor thread
+            # itself, which the lock-free ring allows
+            with tracer.region(
+                    "repro.executor.run", backend_track(self.name, executor),
+                    op=op or getattr(fn, "__name__", "call"),
+                    queued_us=queued * 1e6):
+                return fn(*args)
 
         self._inflight += 1
         try:
@@ -618,7 +617,10 @@ class DisaggregatedBackend(_ExecutorMixin, ModelBackend):
                 return False
         if getattr(seq, "owner_pool", None) is self.decode_engine.pool:
             return True                  # already transferred (retry path)
-        t0 = time.monotonic()
+        tracer = self._tracer
+        # the span lands on the tracer's clock; durations are the same
+        clock = tracer.clock if tracer.enabled else time.monotonic
+        t0 = clock()
         if getattr(seq, "transfer_package", None) is None:
             pkg, n = await self._run("prefill", self._gather_stage, seq,
                                      op="kv_gather")
@@ -637,11 +639,10 @@ class DisaggregatedBackend(_ExecutorMixin, ModelBackend):
         seq.transfer_package = None
         self.transfers += 1
         self.transfer_pages += len(dst)
-        t1 = time.monotonic()
+        t1 = clock()
         # transfer wait accumulates on the sequence so the scheduler
         # can attribute it to the request (carved out of prefill)
         seq.transfer_s = getattr(seq, "transfer_s", 0.0) + (t1 - t0)
-        tracer = self._tracer
         if tracer.enabled:
             tracer.span("KV_TRANSFER", backend_track(self.name, "transfer"),
                         t0, t1, {"pages": len(dst),
@@ -1211,14 +1212,14 @@ class RemoteStubBackend(ModelBackend):
         self._pending[mid] = fut
         self.messages_sent += 1
         tracer = self._tracer
-        t0 = time.monotonic() if tracer.enabled else 0.0
+        t0 = tracer.clock() if tracer.enabled else 0.0
         self.channel.to_server.put_nowait(
             wire_encode({"v": WIRE_VERSION, "id": mid, "op": op,
                          "body": body or {}}))
         msg = await fut
         if tracer.enabled:
             tracer.span(op, backend_track(self.name, "wire"), t0,
-                        time.monotonic(), {"mid": mid})
+                        tracer.clock(), {"mid": mid})
         if "err" in msg:
             raise wire_error_rehydrate(msg["err"], self._mirrors)
         return msg["ok"]

@@ -67,7 +67,7 @@ from repro.serving.backend import (BackendCapacity, BackendLost,
                                    _WIRE_ERRORS, wire_decode, wire_encode,
                                    wire_error_payload,
                                    wire_error_rehydrate)
-from repro.serving.observability.tracer import backend_track
+from repro.serving.observability.tracer import NULL_TRACER, backend_track
 from repro.serving.scheduler.request import BACKEND_LOST
 
 #: hard bound on one frame's payload (a 9-token prompt is ~100 bytes;
@@ -420,14 +420,11 @@ class SocketBackendServer:
             try:
                 if (fast_decode is not None and self._decode_warm
                         and getattr(self.inner, "_inflight", 1) == 0):
-                    t0 = time.monotonic()
-                    fast_decode([s for _, s in live])
-                    tracer = getattr(self.inner, "_tracer", None)
-                    if tracer is not None and tracer.enabled:
-                        tracer.span(
-                            "decode_sweep",
-                            backend_track(self.inner.name, "decode"),
-                            t0, time.monotonic(), {"streamed": True})
+                    tracer = getattr(self.inner, "_tracer", NULL_TRACER)
+                    with tracer.region(
+                            "repro.remote.decode_sweep",
+                            backend_track(self.inner.name, "decode")):
+                        fast_decode([s for _, s in live])
                 else:
                     await self.inner.decode_batch([s for _, s in live])
                     self._decode_warm = True
@@ -776,7 +773,7 @@ class SocketClientBackend(ModelBackend):
         self._pending[mid] = fut
         self.messages_sent += 1
         tracer = self._tracer
-        t0 = time.monotonic() if tracer.enabled else 0.0
+        t0 = tracer.clock() if tracer.enabled else 0.0
         try:
             self._writer.write(encode_frame(
                 {"v": WIRE_VERSION, "id": mid, "op": op,
@@ -793,7 +790,7 @@ class SocketClientBackend(ModelBackend):
                 self._pending.pop(mid, None)
         if tracer.enabled:
             tracer.span(op, backend_track(self.name, "wire"), t0,
-                        time.monotonic(), {"mid": mid})
+                        tracer.clock(), {"mid": mid})
         if "err" in msg:
             raise wire_error_rehydrate(msg["err"], self._mirrors)
         return msg["ok"]

@@ -640,16 +640,14 @@ class Engine:
         with self._device_lock:
             if self._caches_poisoned:
                 raise RuntimeError("paged caches poisoned: cannot spill")
-            t0 = time.time()
-            padded = np.full((self._max_pages,), SCRATCH_PAGE, np.int32)
-            padded[:len(pages)] = pages
-            package = jax.tree.map(
-                np.asarray,
-                self._tier_gather(self._paged_caches, jnp.asarray(padded)))
-            self.tracer.span("SPILL", track=self.trace_track,
-                             t0=t0, t1=time.time(),
-                             args={"pages": len(pages)})
-            return package
+            with self.tracer.region("repro.pool.spill", self.trace_track,
+                                    pages=len(pages)):
+                padded = np.full((self._max_pages,), SCRATCH_PAGE, np.int32)
+                padded[:len(pages)] = pages
+                return jax.tree.map(
+                    np.asarray,
+                    self._tier_gather(self._paged_caches,
+                                      jnp.asarray(padded)))
 
     def _restore_from_host(self, seq: PagedSequence) -> None:
         """Continue a prompt's chunk chain into the host tier: where
@@ -678,19 +676,21 @@ class Engine:
             new = pool.alloc(n)     # may itself spill colder pages
         except OutOfPages:
             return                  # treat as a miss, never as failure
-        t0 = time.time()
-        package = self.host_tier.load([s for _k, s, _pt in run],
-                                      self._max_pages)
-        padded = np.full((self._max_pages,), SCRATCH_PAGE, np.int32)
-        padded[:n] = new
-        try:
-            self._paged_caches = self._tier_scatter(
-                self._paged_caches, package, jnp.asarray(padded))
-            jax.block_until_ready(jax.tree.leaves(self._paged_caches)[0])
-        except Exception:
-            self._caches_poisoned = True
-            pool.decref(new)
-            raise
+        with self.tracer.region("repro.pool.restore", self.trace_track,
+                                pages=n, shared_len=int(shared_len)):
+            package = self.host_tier.load([s for _k, s, _pt in run],
+                                          self._max_pages)
+            padded = np.full((self._max_pages,), SCRATCH_PAGE, np.int32)
+            padded[:n] = new
+            try:
+                self._paged_caches = self._tier_scatter(
+                    self._paged_caches, package, jnp.asarray(padded))
+                jax.block_until_ready(
+                    jax.tree.leaves(self._paged_caches)[0])
+            except Exception:
+                self._caches_poisoned = True
+                pool.decref(new)
+                raise
         # the chunks are device-resident again: retire the host copies
         # (one tier owns a chunk at a time; they re-index on seal)
         self.host_tier.consume([k for k, _s, _pt in run])
@@ -700,9 +700,6 @@ class Engine:
         seq.prefill_pos = shared_len
         seq.shared_prefix_len = shared_len
         seq.insert_from = len(seq.pages) * ps
-        self.tracer.span("RESTORE", track=self.trace_track,
-                         t0=t0, t1=time.time(),
-                         args={"pages": n, "shared_len": int(shared_len)})
 
     def _grow_pages(self, seq: PagedSequence, upto: int) -> None:
         """Extend ``seq`` to hold ``upto`` pages (alloc + block-table
@@ -755,28 +752,31 @@ class Engine:
             raise ValueError(
                 f"chunk_tokens must be a positive multiple of the page "
                 f"size {ps}, got {chunk_tokens}")
-        if not seq.prefix_mapped:
-            self._map_shared_prefix(seq)    # OutOfPages: seq resumable
-            if seq.prefill_done:            # logit-cache fast path
-                return True
+        tracer, track = self.tracer, self.trace_track
         p = seq.prompt_len
-        o = seq.prefill_pos
-        length = p - o if chunk_tokens is None else min(chunk_tokens, p - o)
-        final = o + length >= p
-        span = (self._sealed_span(p, seq.max_new_tokens) if final
-                else (o + length))
-        self._grow_pages(seq, pool.pages_for(span))    # OutOfPages: no-op
-        prompt = jnp.asarray(seq.prompt, jnp.int32)
-        bt = jnp.asarray(seq.block_table)[None]
-        try:
+        with tracer.region("repro.prefill.prepare", track) as region:
+            if not seq.prefix_mapped:
+                self._map_shared_prefix(seq)    # OutOfPages: resumable
+                if seq.prefill_done:            # logit-cache fast path
+                    if tracer.enabled:
+                        region.set(prompt_len=p, tokens=0)
+                    return True
+            o = seq.prefill_pos
+            length = (p - o if chunk_tokens is None
+                      else min(chunk_tokens, p - o))
+            if tracer.enabled:
+                region.set(prompt_len=p, tokens=length)
+            final = o + length >= p
+            span = (self._sealed_span(p, seq.max_new_tokens) if final
+                    else (o + length))
+            self._grow_pages(seq, pool.pages_for(span))  # OutOfPages: no-op
+            prompt = jnp.asarray(seq.prompt, jnp.int32)
+            bt = jnp.asarray(seq.block_table)[None]
             if o == 0 and final:
                 # whole-prompt single call (no resident prefix): the
                 # classic prefill path, padded to its page rounding
                 pad = pool.pages_for(p) * ps
                 toks = jnp.zeros((1, pad), jnp.int32).at[0, :p].set(prompt)
-                logits, self._paged_caches = self._paged_prefill(
-                    self.params, toks, self._paged_caches, bt,
-                    jnp.asarray(p - 1, jnp.int32))
             else:
                 # q_offset tail path: positions < o are read back from
                 # pages earlier chunks (or a resident shared prefix)
@@ -789,23 +789,32 @@ class Engine:
                 toks = jnp.zeros((1, pad), jnp.int32).at[
                     0, :length].set(prompt[o:o + length])
                 last = (p - 1 - o) if final else (length - 1)
-                logits, self._paged_caches = self._paged_prefill_tail(
-                    self.params, toks, self._paged_caches, bt,
-                    jnp.asarray(last, jnp.int32),
-                    jnp.asarray(o, jnp.int32),
-                    jnp.asarray(seq.insert_from, jnp.int32))
+        try:
+            with tracer.region("repro.prefill.dispatch", track):
+                if o == 0 and final:
+                    logits, self._paged_caches = self._paged_prefill(
+                        self.params, toks, self._paged_caches, bt,
+                        jnp.asarray(p - 1, jnp.int32))
+                else:
+                    logits, self._paged_caches = self._paged_prefill_tail(
+                        self.params, toks, self._paged_caches, bt,
+                        jnp.asarray(last, jnp.int32),
+                        jnp.asarray(o, jnp.int32),
+                        jnp.asarray(seq.insert_from, jnp.int32))
             self.prefill_tokens_computed += int(pad)
-            if final:
-                # materialise INSIDE the guard: jax dispatch is async,
-                # so an execution-time failure of the donating jit call
-                # often surfaces only here
-                row = np.asarray(logits)[0, 0]
-                tok = int(np.asarray(self._sample_rows(
-                    jnp.asarray(row)[None], np.asarray([seq.seed]),
-                    np.asarray([p]), temps=[seq.temperature]))[0])
-            else:
-                jax.block_until_ready(
-                    jax.tree.leaves(self._paged_caches)[0])
+            # materialise INSIDE the guard: jax dispatch is async, so an
+            # execution-time failure of the donating jit call often
+            # surfaces only here.  The first token's sampling waits on
+            # the logits row, so its round trip counts here too.
+            with tracer.region("repro.prefill.wait", track):
+                if final:
+                    row = np.asarray(logits)[0, 0]
+                    tok = int(np.asarray(self._sample_rows(
+                        jnp.asarray(row)[None], np.asarray([seq.seed]),
+                        np.asarray([p]), temps=[seq.temperature]))[0])
+                else:
+                    jax.block_until_ready(
+                        jax.tree.leaves(self._paged_caches)[0])
         except Exception:
             # conservatively treat any failure of the donating call as
             # cache loss; the caller still holds (and must release) the
@@ -813,14 +822,15 @@ class Engine:
             # complete rollback
             self._caches_poisoned = True
             raise
-        if final:
-            self.prefill_tokens_shared += seq.shared_prefix_len
-            if self._logit_cache_cap > 0:
-                self.logit_cache_misses += 1
-                self._logit_cache_put(self._prompt_key(seq.prompt), row)
-            self._seal_prefill(seq, tok)
-        else:
-            seq.prefill_pos = o + length
+        with tracer.region("repro.prefill.commit", track):
+            if final:
+                self.prefill_tokens_shared += seq.shared_prefix_len
+                if self._logit_cache_cap > 0:
+                    self.logit_cache_misses += 1
+                    self._logit_cache_put(self._prompt_key(seq.prompt), row)
+                self._seal_prefill(seq, tok)
+            else:
+                seq.prefill_pos = o + length
         return seq.prefill_done
 
     def prefill_into_pages(self, prompt, *, max_new_tokens: int,
@@ -865,6 +875,66 @@ class Engine:
         cap = self._decode_batch
         if len(seqs) > cap:
             raise ValueError(f"{len(seqs)} sequences > decode_batch={cap}")
+        tracer, track = self.tracer, self.trace_track
+        with tracer.region("repro.decode.prepare", track) as region:
+            tokens, bt, pos, seeds, temps, src, dst, cow = \
+                self._prepare_decode(seqs, region)
+        try:
+            with tracer.region("repro.decode.dispatch", track):
+                if cow:
+                    logits, self._paged_caches = self._paged_decode_cow(
+                        self.params, jnp.asarray(tokens),
+                        self._paged_caches, jnp.asarray(bt),
+                        jnp.asarray(pos), jnp.asarray(src),
+                        jnp.asarray(dst))
+                else:
+                    logits, self._paged_caches = self._paged_decode(
+                        self.params, jnp.asarray(tokens),
+                        self._paged_caches, jnp.asarray(bt),
+                        jnp.asarray(pos))
+                # row i's next token sits at position pos[i] + 1;
+                # keying the sample by (seq.seed, position) keeps a
+                # sampled generation independent of batch composition
+                picked = self._sample_rows(logits[:, 0], seeds, pos + 1,
+                                           temps=temps)
+            # materialise inside the guard — async dispatch surfaces
+            # jit failures here, after the caches were already donated
+            with tracer.region("repro.decode.wait", track):
+                nxt = np.asarray(picked)
+        except Exception:
+            self._caches_poisoned = True    # donated buffers are gone
+            self.pool.decref([n for _, _, _, _, n in cow])
+            raise
+        with tracer.region("repro.decode.commit", track):
+            for i, seq, idx, old, new in cow:
+                # the copy diverged from the indexed prefix the moment
+                # the step inserted, so this sequence stops backing
+                # entries for the old page; the remaining holders keep
+                # them valid
+                seq.prefix_keys = self.pool.disown_prefix(seq.prefix_keys,
+                                                          old)
+                self.pool.decref([old])
+                seq.pages[idx] = new
+                seq.block_table[idx] = new
+                self.cow_count += 1
+                tracer.instant("cow", track=track,
+                               args={"old": int(old), "new": int(new),
+                                     "fused": True})
+            for i, seq in enumerate(seqs):
+                seq.pos += 1
+                seq.last_token = int(nxt[i])
+                seq.tokens.append(int(nxt[i]))
+                self._reclaim_out_of_span(seq)
+        return nxt[:len(seqs)]
+
+    def _prepare_decode(self, seqs: Sequence[PagedSequence], region):
+        """Host side of one decode step, before any device work: page
+        growth, the copy-on-write check and the step's numpy inputs.
+        On a live tracer, sets the step's counters on ``region``:
+        ``rows``, ``pages_reserved`` (pages the rows map) and
+        ``pages_written`` (of those, pages holding at least one token
+        once this step inserts)."""
+        cap = self._decode_batch
         ps = self.pool.page_size
         # lazy decode-budget allocation: a sequence sealed without its
         # full decode span grows page-by-page as it advances (no-op for
@@ -918,6 +988,14 @@ class Engine:
             pos[i] = seq.pos
             seeds[i] = np.uint32(seq.seed)
             temps[i] = seq.temperature
+        if self.tracer.enabled:
+            # slots below reclaimed_upto were handed back (span reclaim)
+            region.set(
+                rows=len(seqs),
+                pages_reserved=sum(len(s.pages) - s.reclaimed_upto
+                                   for s in seqs),
+                pages_written=sum(self.pool.pages_for(s.pos + 1)
+                                  - s.reclaimed_upto for s in seqs))
         # COWing rows decode against their private copy: the fused jit
         # copies old -> new across every layer slab, then the insert
         # lands in the copy (rows that don't COW ride scratch -> scratch)
@@ -927,45 +1005,7 @@ class Engine:
             bt[i, idx] = new
             src[r] = old
             dst[r] = new
-        try:
-            if cow:
-                logits, self._paged_caches = self._paged_decode_cow(
-                    self.params, jnp.asarray(tokens), self._paged_caches,
-                    jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(src),
-                    jnp.asarray(dst))
-            else:
-                logits, self._paged_caches = self._paged_decode(
-                    self.params, jnp.asarray(tokens), self._paged_caches,
-                    jnp.asarray(bt), jnp.asarray(pos))
-            # row i's next token sits at position pos[i] + 1; keying
-            # the sample by (seq.seed, position) keeps a sampled
-            # generation independent of batch composition.  Materialise
-            # inside the guard — async dispatch surfaces jit failures
-            # here, after the caches were already donated.
-            nxt = np.asarray(self._sample_rows(logits[:, 0], seeds, pos + 1,
-                                               temps=temps))
-        except Exception:
-            self._caches_poisoned = True    # donated buffers are gone
-            self.pool.decref([n for _, _, _, _, n in cow])
-            raise
-        for i, seq, idx, old, new in cow:
-            # the copy diverged from the indexed prefix the moment the
-            # step inserted, so this sequence stops backing entries for
-            # the old page; the remaining holders keep them valid
-            seq.prefix_keys = self.pool.disown_prefix(seq.prefix_keys, old)
-            self.pool.decref([old])
-            seq.pages[idx] = new
-            seq.block_table[idx] = new
-            self.cow_count += 1
-            self.tracer.instant("cow", track=self.trace_track,
-                                args={"old": int(old), "new": int(new),
-                                      "fused": True})
-        for i, seq in enumerate(seqs):
-            seq.pos += 1
-            seq.last_token = int(nxt[i])
-            seq.tokens.append(int(nxt[i]))
-            self._reclaim_out_of_span(seq)
-        return nxt[:len(seqs)]
+        return tokens, bt, pos, seeds, temps, src, dst, cow
 
     # ---- speculative decoding: verify + draft-page rollback ----------
     def verify_step_batch(self, rows: Sequence[Tuple[PagedSequence,

@@ -260,8 +260,9 @@ class PagePool:
         # each may yet need refcount-1 copy-on-write allocations
         self._cow_risk: Set[int] = set()
         self.peak_in_use = 0
-        # tracing: alloc/free instants record here when a backend binds
-        # a live tracer (it sets both attrs); the null default is free
+        # tracing: the tiered pool's retain instants record here when a
+        # backend binds a live tracer (it sets both attrs); the null
+        # default is free
         self.tracer = NULL_TRACER
         self.trace_track = "pool/events"
 
@@ -331,10 +332,6 @@ class PagePool:
             for pg in pages:
                 self._ref[pg] = 1
             self.peak_in_use = max(self.peak_in_use, len(self._ref))
-            if self.tracer.enabled:
-                self.tracer.instant("page_alloc", track=self.trace_track,
-                                    args={"n": n,
-                                          "free": len(self._free)})
             return pages
 
     def refcount(self, page: int) -> int:
@@ -366,7 +363,6 @@ class PagePool:
                 raise ValueError(
                     f"double free / foreign pages "
                     f"{sorted(bad) or list(pages)}")
-            freed = 0
             for pg in pages:
                 pg = int(pg)
                 self._ref[pg] -= 1
@@ -375,14 +371,9 @@ class PagePool:
                     self._index.drop_page(pg)
                     self._cow_risk.discard(pg)
                     heapq.heappush(self._free, pg)
-                    freed += 1
                 elif self._ref[pg] == 1:
                     # exclusive again: no copy-on-write can be pending
                     self._cow_risk.discard(pg)
-            if freed and self.tracer.enabled:
-                self.tracer.instant("page_free", track=self.trace_track,
-                                    args={"n": freed,
-                                          "free": len(self._free)})
 
     def free(self, pages: Sequence[int]) -> None:
         """Decref-to-zero compatibility alias: with refcounts, "free"

@@ -1,11 +1,13 @@
 """Request-level tracing and flight recorder for the serving stack.
 
 One :class:`Tracer` per scheduler records span timelines (ADMIT /
-QUEUED / PREFILL_CHUNK[i] / KV_TRANSFER / DECODE_STEP / FINISH, each
-carrying model/backend/page-count attributes), process-wide instant
-events for scheduler decisions (degrade, shed, COW, OutOfPages
-requeue, logit-cache hit, prewarm), and periodic gauge samples — all
-into one bounded lock-free ring buffer.  ``tracer.export(path)``
+QUEUED / PREFILL_CHUNK[i] / KV_TRANSFER / FINISH, each carrying
+model/backend/page-count attributes), live regions at the layer
+boundaries of the served path (``repro.*``, see :meth:`Tracer.region`),
+process-wide instant events for scheduler decisions (degrade, shed,
+COW, OutOfPages requeue, logit-cache hit, prewarm) and backend
+compiles (``repro.compile``), and periodic gauge samples — all into
+one bounded lock-free ring buffer.  ``tracer.export(path)``
 renders the buffer as Chrome trace-event / Perfetto JSON with one
 track per backend executor and one per request, so bench_disagg's
 interleaved-vs-disagg ITL win is visible as a timeline.
@@ -27,7 +29,15 @@ Design constraints, in order:
 * **Spans are recorded after the fact.**  ``span(name, track, t0,
   t1)`` takes both endpoints, so there is no per-thread span stack to
   maintain and a span costs one tuple — the caller already holds the
-  two timestamps it took for metrics.
+  two timestamps it took for metrics.  ``region(name, track, **args)``
+  is the live form for code that holds no timestamps: a context
+  manager that takes both on ``tracer.clock`` and also opens a
+  ``jax.profiler.TraceAnnotation``, so inside a profiler session the
+  span lands in the JAX profiler's trace on the profiler's own clock,
+  beside the device operations, with its arguments as stats (outside
+  a session the annotation records nothing).  Hot paths pass no
+  arguments to ``region`` and compute their counters only under
+  ``if tracer.enabled:``, so a disabled region allocates nothing.
 
 The flight recorder is the same buffer viewed backwards:
 ``flight_recorder_dump(path)`` writes the last N seconds of events,
@@ -40,7 +50,9 @@ from __future__ import annotations
 
 import itertools
 import json
+import threading
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 # Event tuples: (seq, ph, name, track, ts, dur, args)
@@ -74,6 +86,94 @@ def backend_track(backend_name: str, executor: str) -> str:
     return f"backend:{backend_name}/{executor}"
 
 
+#: the backend-compile event of ``jax.monitoring`` (seconds per XLA
+#: compile; a persistent-cache hit does not fire it)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class _NullRegion:
+    """The region of a disabled tracer: enters, takes arguments and
+    exits without reading a clock.  One instance is shared."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args):
+        pass
+
+
+_NULL_REGION = _NullRegion()
+
+
+class _Region:
+    """One live span: ``tracer.clock`` at enter and exit, recorded in
+    the ring at exit, and a ``jax.profiler.TraceAnnotation`` with the
+    arguments as stats."""
+
+    __slots__ = ("_tracer", "_name", "_track", "_args", "_t0", "_ann")
+
+    def __init__(self, tracer, name, track, args):
+        self._tracer = tracer
+        self._name = name
+        self._track = track
+        self._args = args
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
+        self._t0 = self._tracer.clock()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self._tracer.clock()
+        self._tracer._record(SPAN, self._name, self._track, self._t0,
+                             t1 - self._t0, self._args or None)
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **args):
+        """Add arguments known only inside the region (counters the
+        region's own work computes)."""
+        self._args.update(args)
+        self._ann.set_metadata(**args)
+
+
+# Every enabled tracer that counts compiles.  jax.monitoring listeners
+# are process-wide and cannot be removed, so one listener, registered
+# on first use, feeds whichever tracers are alive.
+_COMPILE_SINKS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_COMPILE_LOCK = threading.Lock()
+_compile_listener_registered = False
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    if event == COMPILE_EVENT:
+        # snapshot under the lock: another thread may be registering a
+        # tracer, and a set that grows while iterated raises (which
+        # would fail the jit call that reported the compile)
+        with _COMPILE_LOCK:
+            sinks = list(_COMPILE_SINKS)
+        for tracer in sinks:
+            tracer._on_compile(seconds)
+
+
+def _count_compiles(tracer: "Tracer") -> None:
+    global _compile_listener_registered
+    with _COMPILE_LOCK:
+        _COMPILE_SINKS.add(tracer)
+        if not _compile_listener_registered:
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _compile_listener_registered = True
+
+
 class NullTracer:
     """Tracing disabled: every method is a literal no-op and
     ``enabled`` is False so hot paths skip even the timestamp reads.
@@ -86,6 +186,9 @@ class NullTracer:
 
     def span(self, name, track, t0, t1, args=None):  # pragma: no cover
         pass
+
+    def region(self, name, track, **args):
+        return _NULL_REGION
 
     def instant(self, name, track=SCHED_TRACK, args=None, t=None):
         pass
@@ -136,6 +239,10 @@ class Tracer:
         self.trips = 0                       # trip() calls (rate-limited in)
         self.dumps = 0                       # flight-recorder files written
         self._last_dump_t: Optional[float] = None
+        self._compile_lock = threading.Lock()
+        self.compiles = 0                    # backend compiles seen
+        self.compile_s = 0.0                 # and their seconds
+        _count_compiles(self)
 
     # ---- recording ----------------------------------------------------
     def _record(self, ph: str, name: str, track: str, ts: float,
@@ -158,6 +265,22 @@ class Tracer:
         if not self.enabled:
             return
         self._record(SPAN, name, track, t0, t1 - t0, args)
+
+    def region(self, name: str, track: str, **args: Any) -> _Region:
+        """A live span around a ``with`` block, on ``self.clock``;
+        ``region.set(**args)`` adds arguments computed inside it.  On
+        :data:`NULL_TRACER` this is one shared no-op context."""
+        if not self.enabled:
+            return _NULL_REGION
+        return _Region(self, name, track, args)
+
+    def _on_compile(self, seconds: float) -> None:
+        if not self.enabled:
+            return
+        with self._compile_lock:
+            self.compiles += 1
+            self.compile_s += seconds
+        self.instant("repro.compile", args={"seconds": seconds})
 
     def instant(self, name: str, track: str = SCHED_TRACK,
                 args: Optional[Dict[str, Any]] = None,
@@ -205,6 +328,8 @@ class Tracer:
             "consumers": len(self._consumers),
             "trips": self.trips,
             "flight_recorder_dumps": self.dumps,
+            "compiles": self.compiles,
+            "compile_s": self.compile_s,
         }
 
     # ---- Chrome trace-event / Perfetto export -------------------------

@@ -796,6 +796,60 @@ class PagedLLMScheduler(SchedulerLifecycle):
     def _fits_ever(self, backend: ModelBackend, req: Request) -> bool:
         return backend.fits_ever(len(req.x), req.max_new_tokens)
 
+    def _admit(self, m: int, chunk_tokens: Optional[int]) -> bool:
+        """Begin prefill (host side) for queued requests while decode
+        slots and pages allow; True when anything moved."""
+        backend = self.backends[m]
+        queue, slots = self.queues[m], self.slots[m]
+        prefilling = self._prefilling[m]
+        progressed = False
+        while (len(queue)
+               and len(slots) + len(prefilling) < slots.capacity):
+            nxt = queue.peek()
+            if nxt.is_terminal:         # cancelled while queued:
+                queue.pop()             # future already resolved
+                progressed = True
+                continue
+            if not self._fits_ever(backend, nxt):
+                req = queue.pop()
+                cap = backend.capacity()
+                if req.fail(OutOfPages(
+                        f"request needs more pages than the whole "
+                        f"pool ({len(req.x)} + {req.max_new_tokens} "
+                        f"tokens > {cap.num_pages * cap.page_size} "
+                        f"poolable)"), self.clock()):
+                    self.metrics.on_fail(req)
+                progressed = True
+                continue
+            if not backend.admissible(nxt.x, nxt.max_new_tokens,
+                                      chunk_tokens=chunk_tokens):
+                break                   # backpressure: await frees
+            req = queue.pop()
+            req.state = RequestState.PREFILLING
+            req.started_t = self.clock()   # per request, not sweep
+            try:
+                # host-side validation only: the shared-prefix
+                # mapping and logit-cache fast path run lazily
+                # in the first prefill chunk (see _run_chunk)
+                seq = backend.begin(
+                    req.x, max_new_tokens=req.max_new_tokens,
+                    seed=req.seed,
+                    temperature=req.params.temperature,
+                    stop_tokens=req.params.stop_tokens)
+            except Exception as exc:
+                if req.fail(exc, self.clock()):
+                    self.metrics.on_fail(req)
+                continue                # request-local: keep going
+            progressed = True
+            seq.trace_rid = req.rid   # lets backend-side spans
+            #   (KV_TRANSFER) name the request they serve
+            seq.deadline_t = req.deadline_t  # EDF key for the
+            #   disaggregated KV-transfer turnstile (and the
+            #   cluster wire's deadline_rel)
+            req.on_prefill_progress(seq.prefill_pos, self.clock())
+            prefilling.append(_Prefilling(req, seq))
+        return progressed
+
     async def _worker(self, m: int) -> None:
         backend = self.backends[m]
         queue, slots, event = self.queues[m], self.slots[m], self._events[m]
@@ -803,6 +857,8 @@ class PagedLLMScheduler(SchedulerLifecycle):
         chunk_tokens = self._chunk_tokens(backend)
         concurrent = bool(backend.concurrent_prefill)
         chunk_task: Optional[asyncio.Task] = None
+        # the loop's host work between awaits (a region never spans one)
+        track = backend_track(backend.name, "scheduler")
         try:
             while True:
                 progressed = False
@@ -816,51 +872,8 @@ class PagedLLMScheduler(SchedulerLifecycle):
                     progressed = progressed or ran
 
                 # ---- admit: begin prefill (host-side) ---------------
-                while (len(queue)
-                       and len(slots) + len(prefilling) < slots.capacity):
-                    nxt = queue.peek()
-                    if nxt.is_terminal:         # cancelled while queued:
-                        queue.pop()             # future already resolved
-                        progressed = True
-                        continue
-                    if not self._fits_ever(backend, nxt):
-                        req = queue.pop()
-                        cap = backend.capacity()
-                        if req.fail(OutOfPages(
-                                f"request needs more pages than the whole "
-                                f"pool ({len(req.x)} + {req.max_new_tokens} "
-                                f"tokens > {cap.num_pages * cap.page_size} "
-                                f"poolable)"), self.clock()):
-                            self.metrics.on_fail(req)
-                        progressed = True
-                        continue
-                    if not backend.admissible(nxt.x, nxt.max_new_tokens,
-                                              chunk_tokens=chunk_tokens):
-                        break                   # backpressure: await frees
-                    req = queue.pop()
-                    req.state = RequestState.PREFILLING
-                    req.started_t = self.clock()   # per request, not sweep
-                    try:
-                        # host-side validation only: the shared-prefix
-                        # mapping and logit-cache fast path run lazily
-                        # in the first prefill chunk (see _run_chunk)
-                        seq = backend.begin(
-                            req.x, max_new_tokens=req.max_new_tokens,
-                            seed=req.seed,
-                            temperature=req.params.temperature,
-                            stop_tokens=req.params.stop_tokens)
-                    except Exception as exc:
-                        if req.fail(exc, self.clock()):
-                            self.metrics.on_fail(req)
-                        continue                # request-local: keep going
-                    progressed = True
-                    seq.trace_rid = req.rid   # lets backend-side spans
-                    #   (KV_TRANSFER) name the request they serve
-                    seq.deadline_t = req.deadline_t  # EDF key for the
-                    #   disaggregated KV-transfer turnstile (and the
-                    #   cluster wire's deadline_rel)
-                    req.on_prefill_progress(seq.prefill_pos, self.clock())
-                    prefilling.append(_Prefilling(req, seq))
+                with self.tracer.region("repro.sched.admit", track):
+                    progressed = self._admit(m, chunk_tokens) or progressed
 
                 # ---- chunk: one prefill chunk, earliest deadline ----
                 if chunk_task is None and prefilling:
@@ -932,38 +945,8 @@ class PagedLLMScheduler(SchedulerLifecycle):
                         self._kill_backend(m, exc)
                         return
                     t1 = self.clock()
-                    # count only after the step ran: the COW-failure
-                    # retry path above must not double-count a batch
-                    # that never executed
-                    if len({e.admit_step for e in active}) > 1:
-                        self.mixed_admission_batches += 1
-                    self.decode_batches += 1
-                    self.metrics.on_batch(m, len(active), slots.capacity)
-                    self.metrics.on_model_busy(m, t1 - t0)
-                    self.tokens_generated += sum(
-                        len(e.seq.tokens) - n0
-                        for e, n0 in zip(active, before))
-                    if self.tracer.enabled:
-                        self.tracer.span(
-                            "DECODE_STEP", backend_track(backend.name,
-                                                         "decode"),
-                            t0, t1,
-                            {"model": m, "batch": len(active),
-                             "pages": sum(len(getattr(e.seq, "pages", ()))
-                                          for e in active)})
-                    for e, n0 in zip(active, before):
-                        new = e.seq.tokens[n0:]
-                        if not e.req.is_terminal:
-                            for j, tok in enumerate(new):
-                                e.req.on_token(
-                                    int(tok),
-                                    e.seq.pos - len(new) + 1 + j, t1)
-                        if e.last_token_t:
-                            self.metrics.on_decode_gap(m,
-                                                       t1 - e.last_token_t)
-                        e.last_token_t = t1
-                        if e.seq.done:
-                            self._retire(m, e, t1)
+                    with self.tracer.region("repro.sched.emit", track):
+                        self._emit_step(m, active, before, t0, t1)
                     continue
 
                 if progressed:
@@ -992,6 +975,31 @@ class PagedLLMScheduler(SchedulerLifecycle):
             if chunk_task is not None:
                 with contextlib.suppress(BaseException):
                     await chunk_task
+
+    def _emit_step(self, m: int, active, before: List[int], t0: float,
+                   t1: float) -> None:
+        """After a decode step: count it, emit each row's new tokens
+        and retire the rows that finished."""
+        # count only after the step ran: the COW-failure retry path
+        # must not double-count a batch that never executed
+        if len({e.admit_step for e in active}) > 1:
+            self.mixed_admission_batches += 1
+        self.decode_batches += 1
+        self.metrics.on_batch(m, len(active), self.slots[m].capacity)
+        self.metrics.on_model_busy(m, t1 - t0)
+        self.tokens_generated += sum(len(e.seq.tokens) - n0
+                                     for e, n0 in zip(active, before))
+        for e, n0 in zip(active, before):
+            new = e.seq.tokens[n0:]
+            if not e.req.is_terminal:
+                for j, tok in enumerate(new):
+                    e.req.on_token(int(tok),
+                                   e.seq.pos - len(new) + 1 + j, t1)
+            if e.last_token_t:
+                self.metrics.on_decode_gap(m, t1 - e.last_token_t)
+            e.last_token_t = t1
+            if e.seq.done:
+                self._retire(m, e, t1)
 
     async def _run_chunk(self, m: int, ent: _Prefilling,
                          chunk_tokens: Optional[int]) -> Optional[bool]:

@@ -98,6 +98,27 @@ def test_spill_restore_parity_on_vs_off(variant):
     assert eng.pool.pages_in_use == 0 and eng.pool.prefix_entries == 0
 
 
+def test_spill_and_restore_spans_lie_on_the_tracer_clock():
+    """The spill and restore spans take their times from the tracer's
+    clock, so they fall inside the run's own interval on that clock."""
+    from repro.serving.observability import Tracer
+    cfg = tiny_config("full")
+    params = tf.init_params(cfg, jax.random.key(3))
+    pa, _ = prompts_with_shared_prefix(cfg)
+    eng = make_tiered_engine(cfg, params)
+    eng.tracer = tracer = Tracer()
+    t0 = tracer.clock()
+    eng.generate_paged(pa, max_new_tokens=4)
+    eng.pool.drop_retained()                    # spills
+    eng.generate_paged(pa, max_new_tokens=4)    # restores
+    t1 = tracer.clock()
+    spans = {ev[2]: ev for ev in tracer.events() if ev[1] == "X"}
+    for name in ("repro.pool.spill", "repro.pool.restore"):
+        _, _, _, _, ts, dur, args = spans[name]
+        assert t0 <= ts and ts + dur <= t1
+        assert args["pages"] == 3
+
+
 def test_restored_prefix_tokens_count_as_shared():
     """A restored prefix is shared compute, not recomputed compute:
     the repeat generation's sealing accounts its restored span in

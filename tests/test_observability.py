@@ -105,6 +105,172 @@ def test_null_tracer_is_disabled_noop():
     NULL_TRACER.add_consumer(lambda ev: None)
 
 
+def test_null_tracer_region_is_one_shared_noop():
+    calls = []
+    clock = lambda: calls.append(1) or 0.0      # noqa: E731
+    r1 = NULL_TRACER.region("repro.a", "t/x", rows=1)
+    r2 = NULL_TRACER.region("repro.b", "t/y")
+    assert r1 is r2
+    with r1 as r:
+        r.set(pages=3)
+    # a disabled Tracer hands out the same no-op and reads no clock
+    off = Tracer(clock=clock)
+    off.enabled = False
+    assert off.region("repro.c", "t/z") is r1
+    with off.region("repro.c", "t/z"):
+        pass
+    assert calls == [] and off.events() == []
+
+
+def test_region_records_a_span_on_the_tracer_clock():
+    tr = Tracer(clock=fake_clock(10.0))
+    with tr.region("repro.outer", "engine/e", rows=2) as outer:
+        with tr.region("repro.inner", "engine/e"):
+            pass
+        outer.set(pages_written=5)
+    with pytest.raises(KeyError):
+        with tr.region("repro.failed", "engine/e"):
+            raise KeyError("recorded all the same")
+    inner, outer, failed = [ev for ev in tr.events() if ev[1] == "X"]
+    # ticks of 1 ms: outer enters at 10.001, inner spans 10.002-10.003
+    assert inner[2:6] == ("repro.inner", "engine/e", pytest.approx(10.002),
+                          pytest.approx(0.001))
+    assert outer[2:6] == ("repro.outer", "engine/e", pytest.approx(10.001),
+                          pytest.approx(0.003))
+    assert outer[6] == {"rows": 2, "pages_written": 5}
+    assert inner[6] is None
+    assert failed[2] == "repro.failed"
+
+
+def _profiled_host_events(tmp_path, body):
+    """Run ``body`` under a JAX profiler session and return the
+    ``repro.`` host events of the trace it wrote."""
+    import warnings
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    out = []
+    with warnings.catch_warnings():
+        # jaxlib's stats type predates Python 3.12's __module__ check
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
+                        for e in line.events if e.name.startswith("repro.")]
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_profiler_regions_land_in_the_xplane_with_their_stats(tmp_path):
+    tr = Tracer()
+    other = Tracer()
+
+    def body():
+        with tr.region("repro.decode.prepare", "engine/e", rows=3) as r:
+            r.set(pages_reserved=7, pages_written=6)
+            with tr.region("repro.decode.dispatch", "engine/e"):
+                jax.jit(lambda x: x * 2)(np.ones(4)).block_until_ready()
+        with other.region("repro.sched.emit", "sched/s"):
+            pass
+        with NULL_TRACER.region("repro.disabled", "engine/e"):
+            pass
+
+    # outside a profiler session the annotations record nothing
+    with tr.region("repro.before_session", "engine/e"):
+        pass
+    events = _profiled_host_events(tmp_path, body)
+    # every enabled tracer annotates; a disabled one does not
+    assert [e[0] for e in events] == ["repro.decode.prepare",
+                                      "repro.decode.dispatch",
+                                      "repro.sched.emit"]
+    (_, p0, pdur, stats), (_, d0, ddur, _), _emit = events
+    assert stats == {"rows": 3, "pages_reserved": 7, "pages_written": 6}
+    assert p0 <= d0 and d0 + ddur <= p0 + pdur
+    # the ring holds the same spans, on the tracer's clock
+    spans = [ev[2] for ev in tr.events() if ev[1] == "X"]
+    assert spans == ["repro.before_session", "repro.decode.dispatch",
+                     "repro.decode.prepare"]
+    assert [ev[2] for ev in other.events()
+            if ev[1] == "X"] == ["repro.sched.emit"]
+
+
+def test_backend_compiles_are_counted_into_every_live_tracer():
+    a, b = Tracer(), Tracer()
+    before = a.stats()["compiles"]
+    jax.jit(lambda x: x * 3 + 1)(np.arange(5.0)).block_until_ready()
+    for tr in (a, b):
+        st = tr.stats()
+        assert st["compiles"] >= 1 and st["compile_s"] > 0.0
+        compiles = [ev for ev in tr.events() if ev[2] == "repro.compile"]
+        assert len(compiles) == st["compiles"]
+        assert all(ev[6]["seconds"] > 0.0 for ev in compiles)
+    assert a.stats()["compiles"] > before
+
+
+def test_compile_counts_survive_concurrent_listeners():
+    """Compiles are reported on whichever thread compiles: concurrent
+    reports must not lose a count."""
+    import sys
+    import threading
+    tr = Tracer(capacity=1 << 14)
+    threads, per = 8, 500
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        workers = [threading.Thread(
+            target=lambda: [tr._on_compile(0.5) for _ in range(per)])
+            for _ in range(threads)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=30)
+        assert not any(w.is_alive() for w in workers)
+    finally:
+        sys.setswitchinterval(old)
+    st = tr.stats()
+    assert st["compiles"] == threads * per
+    assert st["compile_s"] == threads * per * 0.5
+
+
+def test_tracers_built_while_compiles_are_reported():
+    """A compile reported on one thread while another thread builds a
+    tracer: the listener must not iterate the growing set of tracers
+    (that raises inside the jit call that reported the compile)."""
+    import sys
+    import threading
+    from repro.serving.observability import tracer as tracer_mod
+    keep = [Tracer(capacity=4) for _ in range(2000)]
+    stop, errors = threading.Event(), []
+
+    def report():
+        while not stop.is_set():
+            try:
+                tracer_mod._on_duration(tracer_mod.COMPILE_EVENT, 0.0)
+            except Exception as exc:     # noqa: BLE001 - the failure
+                errors.append(exc)
+                return
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    reporter = threading.Thread(target=report)
+    reporter.start()
+    try:
+        for _ in range(5000):
+            keep.append(Tracer(capacity=4))
+    finally:
+        stop.set()
+        reporter.join(timeout=30)
+        sys.setswitchinterval(old)
+    assert not reporter.is_alive()
+    assert errors == []
+
+
 def test_chrome_export_is_schema_valid(tmp_path):
     tr = Tracer(clock=fake_clock())
     tr.span("PREFILL", request_track(3), 1.0, 1.5, {"model": 0})
@@ -163,6 +329,28 @@ def serve(model, kind, tracer=None):
     return sched, handles, [np.asarray(o) for o in outs]
 
 
+@pytest.mark.parametrize("kind", ["inproc", "disagg"])
+def test_untraced_run_builds_no_span_arguments(model, kind, monkeypatch):
+    """With tracing off the served path only enters the shared no-op
+    region: it computes no counters for it and names no executor
+    track, so tracing off costs one method call per region."""
+    from repro.serving import backend as backend_mod
+    from repro.serving.observability import tracer as tracer_mod
+    counters, tracks = [], []
+    monkeypatch.setattr(tracer_mod._NullRegion, "set",
+                        lambda self, **args: counters.append(args))
+    real_track = backend_mod.backend_track
+    monkeypatch.setattr(backend_mod, "backend_track",
+                        lambda *a: tracks.append(a) or real_track(*a))
+    _, _, outs = serve(model, kind)
+    assert len(outs) == len(PROMPT_LENS)
+    assert counters == []
+    # binding the (null) tracer names the engine and pool tracks once;
+    # the executor calls name none
+    assert [a for a in tracks
+            if not a[1].endswith(("engine", "pool"))] == []
+
+
 def chain_of(events, rid):
     track = request_track(rid)
     return [(ph, name, ts, dur)
@@ -185,7 +373,32 @@ def test_traced_run_chains_close_and_tokens_match_untraced(model, kind,
 
     events = tracer.events()
     names = {ev[2] for ev in events}
-    assert "DECODE_STEP" in names          # backend decode track spans
+    # the served path's regions: the executor call, the engine's four
+    # phases of a decode step and of a prefill chunk, the scheduler's
+    # host work; the scheduler no longer times the decode call itself
+    assert {"repro.executor.run", "repro.sched.admit",
+            "repro.sched.emit"} <= names
+    for phase in ("prepare", "dispatch", "wait", "commit"):
+        assert f"repro.decode.{phase}" in names
+        assert f"repro.prefill.{phase}" in names
+    assert "DECODE_STEP" not in names
+    # one decode prepare inside each executor decode call, carrying the
+    # step's rows and pages (written never exceeds reserved)
+    spans = [ev for ev in events if ev[1] == "X"]
+    runs = [ev for ev in spans if ev[2] == "repro.executor.run"
+            and ev[6]["op"] == "decode_step"]
+    assert len(runs) == sched.decode_batches > 0
+    prepares = [ev for ev in spans if ev[2] == "repro.decode.prepare"]
+    for run in runs:
+        inside = [ev for ev in prepares
+                  if run[4] <= ev[4] and ev[4] + ev[5] <= run[4] + run[5]]
+        assert len(inside) == 1
+        args = inside[0][6]
+        assert 1 <= args["rows"] <= 4
+        assert 0 < args["pages_written"] <= args["pages_reserved"]
+    assert all(ev[6]["queued_us"] >= 0 for ev in runs)
+    chunks = [ev[6] for ev in spans if ev[2] == "repro.prefill.prepare"]
+    assert all(0 < a["tokens"] <= a["prompt_len"] for a in chunks)
     assert any(n.startswith("PREFILL_CHUNK[") for n in names)
     # KV_TRANSFER spans appear exactly on the disaggregated backend
     assert ("KV_TRANSFER" in names) == (kind == "disagg")

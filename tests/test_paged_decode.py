@@ -186,6 +186,68 @@ def test_page_reclaim_reuse_identical_output():
     assert pool.pages_in_use == 0 and pool.peak_in_use == 4
 
 
+@pytest.mark.parametrize("variant", ["full", "swa"])
+def test_decode_prepare_counts_pages(variant):
+    """``repro.decode.prepare`` carries the step's rows, the pages the
+    rows map and, of those, the pages holding a token once the step
+    inserts; with span reclaim the handed-back pages leave both."""
+    from repro.serving.observability import Tracer
+    cfg = tiny_config(variant)
+    params = tf.init_params(cfg, jax.random.key(0))
+    eng = Engine(cfg, params, ServeConfig(max_len=32))
+    eng.init_paged(num_pages=20, page_size=4, decode_batch=2)
+    eng.tracer = tracer = Tracer()
+    seqs = [eng.prefill_into_pages(np.arange(n) % cfg.vocab_size,
+                                   max_new_tokens=12) for n in (5, 9)]
+    want = []
+    for _ in range(10):
+        mapped = sum(pg is not None for s in seqs for pg in s.pages)
+        held = sum(s.pos // 4 + 1 - s.reclaimed_upto for s in seqs)
+        want.append({"rows": 2, "pages_reserved": mapped,
+                     "pages_written": held})
+        eng.decode_step_batch(seqs)
+    got = [ev[6] for ev in tracer.events()
+           if ev[2] == "repro.decode.prepare"]
+    assert got == want
+    # whole spans are reserved up front: 5 + 12 and 9 + 12 tokens
+    assert want[0] == {"rows": 2, "pages_reserved": 5 + 6,
+                       "pages_written": 2 + 3}
+    if variant == "swa":                # window 6: early pages go back
+        assert seqs[1].reclaimed_upto > 0
+    for s in seqs:
+        eng.pool.release(s)
+
+
+def test_paged_program_names_are_pinned():
+    """The device trace names each paged program ``jit_<function>``;
+    the benchmark's decode-step, prefill and roofline readers find the
+    programs by these names, so a rename fails here instead of
+    silencing those metrics."""
+    import re
+    cfg = tiny_config("full")
+    params = tf.init_params(cfg, jax.random.key(0))
+    eng = Engine(cfg, params, ServeConfig(max_len=32))
+    eng.init_paged(num_pages=8, page_size=4, decode_batch=2)
+    caches, i32 = eng._paged_caches, jnp.int32
+    row, one = jnp.zeros((2,), i32), jnp.asarray(0, i32)
+    bt = jnp.zeros((2, eng._max_pages), i32)
+    tok = jnp.zeros((2, 1), i32)
+    toks = jnp.zeros((1, 8), i32)
+    lowered = {
+        "paged_decode_fn": eng._paged_decode.lower(
+            params, tok, caches, bt, row),
+        "paged_decode_cow_fn": eng._paged_decode_cow.lower(
+            params, tok, caches, bt, row, row, row),
+        "paged_prefill_fn": eng._paged_prefill.lower(
+            params, toks, caches, bt[:1], one),
+        "paged_prefill_tail_fn": eng._paged_prefill_tail.lower(
+            params, toks, caches, bt[:1], one, one, one),
+    }
+    for name, low in lowered.items():
+        module = re.match(r"HloModule (\S+?),", low.compile().as_text())
+        assert module.group(1) == f"jit_{name}"
+
+
 def test_capacity_and_pool_errors():
     cfg = tiny_config("full")
     params = tf.init_params(cfg, jax.random.key(0))
