@@ -50,19 +50,21 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     k_scales=None, v_scales=None,
                     v_dim: Optional[int] = None,
                     grouped: bool = True,
-                    prefetch=None):
+                    prefetch=None,
+                    layer=None):
     """Paged decode attention through the Pallas kernel (KV-head-grouped
     grid, block-table scalar prefetch, int8 dequant in-kernel), for
     callers that checked ``use_pallas()``; their jnp path is the gather
     in attention.paged_decode_attention.  q: (B, H, hd) one token per
     row; lengths: (B,).  ``prefetch`` is the combined (B, M+1) operand
     from :func:`repro.kernels.paged_attention.decode_prefetch`, built
-    once per decode step and shared across layers."""
+    once per decode step and shared across layers.  ``layer`` selects
+    the layer of a stacked (L, P, K, ps, hd) pool."""
     return _paged_pallas(q, k_pages, v_pages, block_tables, lengths,
                          window=window, chunk=chunk, logit_cap=logit_cap,
                          scale=scale, k_scales=k_scales, v_scales=v_scales,
                          v_dim=v_dim, grouped=grouped, prefetch=prefetch,
-                         interpret=_interpret())
+                         layer=layer, interpret=_interpret())
 
 
 def selective_scan(x, dt, b_mat, c_mat, a_mat, d_vec):

@@ -2,14 +2,19 @@
 sliding-window / chunked masks, logit soft-capping, int8 pages).
 
 One query token per batch row attends over that row's KV pages.  Pages
-are pool-wide head-major slabs (num_pages, K, page_size, hd) shared by
-every request, so one (page, kv head) block is a whole (page_size, hd)
-tile, as the TPU's (8, 128) tiling requires of a block's two minor
-dimensions.  Each row's ordered page list arrives as a block-table row
-that is **scalar-prefetched** (pltpu.PrefetchScalarGridSpec) so the
-BlockSpec index_map can steer the K/V DMA to the right page before the
-kernel body runs — the gather never materialises a contiguous per-row
-KV copy in HBM.
+are pool-wide head-major slabs shared by every request, so one (page,
+kv head) block is a whole (page_size, hd) tile, as the TPU's (8, 128)
+tiling requires of a block's two minor dimensions.  The model's pool
+stacks every layer, (G, num_pages, K, page_size, hd), and is carried
+whole through the layer scan: the kernel takes the stacked pool plus
+the layer index ``layer`` and reads that layer's pages in place, so no
+layer slice is ever cut out of the stack.  A single layer's pool
+(num_pages, K, page_size, hd) with ``layer=None`` is accepted too.
+Each row's ordered page list arrives as a block-table row that is
+**scalar-prefetched** (pltpu.PrefetchScalarGridSpec), as is the layer
+index, so the BlockSpec index_map can steer the K/V DMA to the right
+(layer, page) before the kernel body runs — the gather never
+materialises a contiguous per-row KV copy in HBM.
 
 Grid: (batch, kv_heads, num_pages_per_row) with a (g, hd) query block,
 where g = q_heads // kv_heads is the GQA group size.  Each K/V page is
@@ -26,12 +31,13 @@ length — short rows in a mixed-length decode batch do proportionally
 less work, which is the point of paging.
 
 When the pool stores int8, per-(slot, head) bf16 scales ride along as
-two more page slabs of shape (num_pages, K, 1, page_size) — one lane
-row per (page, kv head).  A scale is constant along a slot's features,
-so it factors out of both matmuls and is applied along the key axis of
-the (G, page_size) score / probability tiles: q·(k∘s_k) = (q·k)∘s_k and
-p·(v∘s_v) = (p∘s_v)·v.  No lane-to-sublane reshape of the scale row is
-needed, and HBM traffic stays at the quantized width.
+two more page slabs of shape ([G,] num_pages, K, 1, page_size) — one
+lane row per (page, kv head).  A scale is constant along a slot's
+features, so it factors out of both matmuls and is applied along the
+key axis of the (G, page_size) score / probability tiles:
+q·(k∘s_k) = (q·k)∘s_k and p·(v∘s_v) = (p∘s_v)·v.  No lane-to-sublane
+reshape of the scale row is needed, and HBM traffic stays at the
+quantized width.
 
 :func:`decode_prefetch` packs block tables and lengths into ONE
 (B, M+1) int32 scalar operand that the caller builds once per decode
@@ -58,12 +64,12 @@ NEG_INF = -1e30
 def _paged_attn_kernel(*refs, scale: float, window: Optional[int],
                        chunk: Optional[int], logit_cap: Optional[float],
                        page_size: int, quantized: bool,
-                       length_col: Optional[int]):
-    if length_col is None:
-        bt_ref, len_ref, q_ref, k_ref, v_ref, *rest = refs
-    else:                       # combined (B, M+1) prefetch: lengths ride
-        bt_ref, q_ref, k_ref, v_ref, *rest = refs  # in the last column
-        len_ref = None
+                       length_col: Optional[int], num_scalars: int):
+    # scalar refs: block tables, then lengths unless they ride in the
+    # combined prefetch's last column, then the layer index if stacked
+    bt_ref = refs[0]
+    len_ref = refs[1] if length_col is None else None
+    q_ref, k_ref, v_ref, *rest = refs[num_scalars:]
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -160,9 +166,7 @@ def decode_hbm_bytes(k_pages, v_pages, block_tables, lengths, *,
     grouped=False counts one per (row, q_head, live page) — the exact
     g-fold difference the re-grid removes.
     """
-    kk = int(k_pages.shape[1])
-    ps = int(k_pages.shape[2])
-    hd = int(k_pages.shape[3])
+    kk, ps, hd = (int(d) for d in k_pages.shape[-3:])
     vd = int(v_dim) if v_dim is not None else int(v_pages.shape[-1])
     if quantized is None:
         quantized = k_pages.dtype == jnp.int8
@@ -195,14 +199,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     v_dim: Optional[int] = None,
                     grouped: bool = True,
                     prefetch=None,
+                    layer=None,
                     interpret: bool = False):
-    """q: (B, H, hd); k_pages/v_pages: (P, K, page_size, hd|vd);
+    """q: (B, H, hd); k_pages/v_pages: a stacked pool (L, P, K,
+    page_size, hd|vd) read at layer ``layer`` (int32 scalar, traced
+    ok), or with ``layer=None`` one layer's (P, K, page_size, hd|vd);
     block_tables: (B, M) int32; lengths: (B,) int32 visible tokens per
-    row (query at lengths - 1).  k_scales/v_scales: (P, K, 1, page_size)
-    bf16 when the pages are int8.  ``v_dim`` reads only the leading
-    v_dim features of each v page — with v_pages=k_pages that serves
-    absorbed-MLA decode, where v is the latent's first kv_lora features
-    of the same slab, without a second page store.
+    row (query at lengths - 1).  k_scales/v_scales: ([L,] P, K, 1,
+    page_size) bf16 when the pages are int8.  ``v_dim`` reads only the
+    leading v_dim features of each v page — with v_pages=k_pages that
+    serves absorbed-MLA decode, where v is the latent's first kv_lora
+    features of the same slab, without a second page store.
 
     ``grouped`` grids over KV heads with a (g, hd) query block (each
     page fetched once per GQA group); False keeps the per-head grid as
@@ -214,7 +221,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, hd = q.shape
-    num_pages, kk, ps, _ = k_pages.shape
+    stacked = k_pages.ndim == 5
+    if stacked != (layer is not None):
+        raise ValueError(f"a {k_pages.ndim}-D pool with layer={layer!r}: "
+                         "a stacked (L, P, K, ps, hd) pool needs a layer "
+                         "index, a (P, K, ps, hd) pool takes none")
+    kk, ps = k_pages.shape[-3:-1]
     vd = v_dim if v_dim is not None else v_pages.shape[-1]
     m = block_tables.shape[1]
     g = h // kk
@@ -238,47 +250,47 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         if prefetch.shape != (b, m + 1):
             raise ValueError(f"prefetch shape {prefetch.shape} != ({b}, {m + 1})")
         length_col = m
-        nsp = 1
-        scalars = (prefetch.astype(jnp.int32),)
-
-        def q_idx(b_, h_, i, pf):
-            return (b_, h_, 0, 0)
-
-        def kv_idx(b_, h_, i, pf):
-            return (pf[b_, i], kv_head(h_), 0, 0)
+        scalars = [prefetch.astype(jnp.int32)]
     else:
         length_col = None
-        nsp = 2
-        scalars = (block_tables.astype(jnp.int32), lengths.astype(jnp.int32))
+        scalars = [block_tables.astype(jnp.int32), lengths.astype(jnp.int32)]
+    if stacked:
+        scalars.append(jnp.asarray(layer, jnp.int32).reshape((1,)))
 
-        def q_idx(b_, h_, i, bt, ln):
-            return (b_, h_, 0, 0)
+    # the first scalar ref is always the block table (or the combined
+    # prefetch, whose leading M columns are the block table); a stacked
+    # pool's layer index is the last
+    def q_idx(b_, h_, i, *refs):
+        return (b_, h_, 0, 0)
 
-        def kv_idx(b_, h_, i, bt, ln):
-            return (bt[b_, i], kv_head(h_), 0, 0)
+    def kv_idx(b_, h_, i, *refs):
+        at = (refs[0][b_, i], kv_head(h_), 0, 0)
+        return (refs[-1][0],) + at if stacked else at
 
     kernel = functools.partial(
         _paged_attn_kernel, scale=scale_, window=window, chunk=chunk,
         logit_cap=logit_cap, page_size=ps, quantized=quantized,
-        length_col=length_col)
+        length_col=length_col, num_scalars=len(scalars))
 
     # index maps see the grid indices then the scalar-prefetch ref(s);
-    # the page id for (row b, step i) steers the K/V (and scale) DMAs.
-    # Every block's two minor dims are (ps, hd|vd) or (1, ps): whole
-    # tiles of the head-major pool, as the TPU's tiling requires
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), q_idx),
-        pl.BlockSpec((1, 1, ps, hd), kv_idx),
-        pl.BlockSpec((1, 1, ps, vd), kv_idx),
-    ]
+    # the (layer,) page id for (row b, step i) steers the K/V (and
+    # scale) DMAs.  Every block's two minor dims are (ps, hd|vd) or
+    # (1, ps): whole tiles of the head-major pool, as the TPU's tiling
+    # requires; a stacked pool's layer dim is squeezed out of the block
+    lead = (pl.Squeezed(),) if stacked else ()
+
+    def kv_spec(*tile):
+        return pl.BlockSpec(lead + (1, 1) + tile, kv_idx)
+
+    in_specs = [pl.BlockSpec((1, 1, G, hd), q_idx),
+                kv_spec(ps, hd), kv_spec(ps, vd)]
     args = [qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, 1, ps), kv_idx),
-                     pl.BlockSpec((1, 1, 1, ps), kv_idx)]
+        in_specs += [kv_spec(1, ps), kv_spec(1, ps)]
         args += [k_scales, v_scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=nsp,
+        num_scalar_prefetch=len(scalars),
         grid=(b, nh, m),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G, vd), q_idx),

@@ -16,8 +16,9 @@ Decode uses one of two cache layouts behind the same masking core
 (``masked_decode_attention``):
   * ring buffer of capacity = attention span, one slab per batch slot;
     each slot remembers the absolute position it holds (``pos_buf``)
-  * paged pool — head-major (num_pages, K, page_size, hd) slabs shared
-    by all requests, addressed through per-row block tables, with
+  * paged pool — head-major (G, num_pages, K, page_size, hd) slabs
+    shared by all requests, carried whole through the layer scan and
+    addressed by layer index and per-row block tables, with
     per-row query positions so a decode batch can mix requests at
     different lengths (token-level continuous batching; see
     repro.serving.kv_cache).
@@ -466,13 +467,14 @@ def paged_prefill_attention(q, cache: Params, block_tables, q_offset, *,
                             window: Optional[int] = None,
                             chunk: Optional[int] = None,
                             scale: Optional[float] = None,
-                            logit_cap: Optional[float] = None) -> jnp.ndarray:
+                            logit_cap: Optional[float] = None,
+                            layer=None) -> jnp.ndarray:
     """Tail-prefill attention over the paged pool: queries at absolute
     positions q_offset + arange(S) attend the block-table gather of the
-    pool — the resident shared-prefix pages plus the tail K/V this
-    prefill just wrote.  q: (B, S, H, hd); q_offset is a shared scalar
-    or per-row (B,) (speculative verify), traced ok."""
-    k, v = paged_gather_kv(cache, block_tables)
+    pool's layer ``layer`` — the resident shared-prefix pages plus the
+    tail K/V this prefill just wrote.  q: (B, S, H, hd); q_offset is a
+    shared scalar or per-row (B,) (speculative verify), traced ok."""
+    k, v = paged_gather_kv(cache, block_tables, layer)
     k = shard(k, "batch", None, "kv_heads", None)
     v = shard(v, "batch", None, "kv_heads", None)
     kv_pos = jnp.arange(k.shape[1], dtype=jnp.int32)
@@ -504,18 +506,26 @@ def decode_attention(q, cache: Params, pos, *, window: Optional[int] = None,
 # Paged KV cache + decode attention
 # ---------------------------------------------------------------------------
 #
-# Pages are pool-wide, NOT per batch row: cache["k"] is head-major,
-# (num_pages, K, page_size, hd), so the paged kernel's block for one
-# (page, kv head) is a whole (page_size, hd) tile.  A request owns an
-# ordered list of pages recorded in its block-table row.  Logical
-# token j of a request lives in page block_table[j // page_size] at
-# slot j % page_size, so a gathered view is position-ordered and the
-# mask is simply kv_pos = arange(T) against the per-row query position
-# — the same masked_decode_attention core the ring path uses.  Page 0
-# is reserved as a scratch page: padding block-table entries and
-# inactive batch rows point at it, and everything they write there is
-# masked out.  int8 pools keep per-(slot, head) scales as
-# (num_pages, K, 1, page_size): one lane row per (page, kv head).
+# Pages are pool-wide, NOT per batch row: the model's cache["k"] stacks
+# the G layer groups of a pattern position and is head-major,
+# (G, num_pages, K, page_size, hd), so the paged kernel's block for one
+# (layer, page, kv head) is a whole (page_size, hd) tile.  The layer
+# scan carries the whole stacked pool and every helper below addresses
+# it by ``layer``: a layer's pages are never sliced out of the stack or
+# restacked, so the pool is updated in place.  Writes scatter one (hd,)
+# row per (token, kv head) — the update window is the minor dim alone,
+# so the pool keeps the kernel's (page_size, hd) tile layout and needs
+# no layout copy.  ``layer=None`` addresses a single layer's pool
+# (num_pages, K, page_size, hd).  A request owns an ordered list of
+# pages recorded in its block-table row.  Logical token j of a request
+# lives in page block_table[j // page_size] at slot j % page_size, so a
+# gathered view is position-ordered and the mask is simply
+# kv_pos = arange(T) against the per-row query position — the same
+# masked_decode_attention core the ring path uses.  Page 0 is reserved
+# as a scratch page: padding block-table entries and inactive batch
+# rows point at it, and everything they write there is masked out.
+# int8 pools keep per-(slot, head) scales as (G, num_pages, K, 1,
+# page_size): one lane row per (page, kv head).
 
 SCRATCH_PAGE = 0
 
@@ -523,8 +533,10 @@ SCRATCH_PAGE = 0
 def init_paged_kv_cache(num_pages: int, page_size: int, num_kv_heads: int,
                         head_dim: int, *, v_head_dim: Optional[int] = None,
                         dtype=jnp.bfloat16) -> Params:
-    """Pool-wide paged KV store.  dtype=int8 stores quantized k/v with
-    per-(slot, head) max-abs scales, mirroring the ring cache."""
+    """One layer's pool-wide paged KV store, (num_pages, K, page_size,
+    hd) (the model stacks it over layer groups).  dtype=int8 stores
+    quantized k/v with per-(slot, head) max-abs scales, mirroring the
+    ring cache."""
     v_hd = v_head_dim or head_dim
     if isinstance(dtype, str):
         dtype = jnp.dtype(dtype)
@@ -539,37 +551,46 @@ def init_paged_kv_cache(num_pages: int, page_size: int, num_kv_heads: int,
     return cache
 
 
-def _paged_write(cache: Params, k, v, page, slot) -> Params:
+def _paged_write(cache: Params, k, v, page, slot, layer=None) -> Params:
     """Scatter token K/V (..., K, hd) into the pool at (page, slot)
     index arrays of the same leading shape, quantizing for int8 pools.
-    The page and slot indices sit on either side of the head axis, so
-    the indexed view is (..., K, hd) — the tokens' own layout."""
+    Each (token, kv head) writes one (hd,) row at [layer, page, head,
+    slot]: the update window is the minor dim alone, so the scatter
+    keeps the pool's (page_size, hd) tile layout and runs in place."""
+    heads = jnp.arange(cache["k"].shape[-3], dtype=jnp.int32)
+    at = (page[..., None], heads, slot[..., None])              # (..., K)
+    if layer is not None:
+        at = (layer,) + at
     out = dict(cache)
     if cache["k"].dtype == jnp.int8:
         kq, ks = _quantize(k, jnp.int8)
         vq, vs = _quantize(v, jnp.int8)
-        out["k_scale"] = cache["k_scale"].at[page, :, 0, slot].set(ks)
-        out["v_scale"] = cache["v_scale"].at[page, :, 0, slot].set(vs)
+        at_scale = at[:-1] + (0, at[-1])        # scales ([G,] P, K, 1, ps)
+        out["k_scale"] = cache["k_scale"].at[at_scale].set(ks)
+        out["v_scale"] = cache["v_scale"].at[at_scale].set(vs)
         k, v = kq, vq
-    out["k"] = cache["k"].at[page, :, slot].set(k.astype(cache["k"].dtype))
-    out["v"] = cache["v"].at[page, :, slot].set(v.astype(cache["v"].dtype))
+    out["k"] = cache["k"].at[at].set(k.astype(cache["k"].dtype))
+    out["v"] = cache["v"].at[at].set(v.astype(cache["v"].dtype))
     return out
 
 
 def paged_cache_insert(cache: Params, k_new, v_new, block_tables,
-                       pos) -> Params:
+                       pos, layer=None) -> Params:
     """Insert one token per row: k/v (B, 1, K, hd) at per-row position
-    ``pos`` (B,) via ``block_tables`` (B, M).  Inactive rows should
-    point at SCRATCH_PAGE; colliding scratch writes are harmless."""
-    ps = cache["k"].shape[2]
+    ``pos`` (B,) via ``block_tables`` (B, M), into layer ``layer`` of a
+    stacked pool.  Inactive rows should point at SCRATCH_PAGE;
+    colliding scratch writes are harmless."""
+    ps = cache["k"].shape[-2]
     pos = jnp.asarray(pos, jnp.int32).reshape((-1,))
     page = jnp.take_along_axis(block_tables, (pos // ps)[:, None],
                                axis=1)[:, 0]                    # (B,)
-    return _paged_write(cache, k_new[:, 0], v_new[:, 0], page, pos % ps)
+    return _paged_write(cache, k_new[:, 0], v_new[:, 0], page, pos % ps,
+                        layer)
 
 
 def paged_cache_prefill(cache: Params, k, v, block_tables,
-                        start: int = 0, *, insert_from=None) -> Params:
+                        start: int = 0, *, insert_from=None,
+                        layer=None) -> Params:
     """Write S tokens (B, S, K, hd) at positions start..start+S-1 of
     each row's block-table mapping (prefill into pages).
 
@@ -580,8 +601,9 @@ def paged_cache_prefill(cache: Params, k, v, block_tables,
     those positions for the forward pass but must not touch resident
     shared pages that already hold their K/V.  Positions whose page
     index falls past the block-table width also land on scratch
-    (right-padding of a page-rounded tail near max_len)."""
-    ps = cache["k"].shape[2]
+    (right-padding of a page-rounded tail near max_len).  ``layer``
+    addresses one layer of a stacked pool."""
+    ps = cache["k"].shape[-2]
     s = k.shape[1]
     m = block_tables.shape[1]
     positions = (jnp.asarray(start, jnp.int32).reshape((-1, 1))
@@ -594,26 +616,39 @@ def paged_cache_prefill(cache: Params, k, v, block_tables,
         ins = jnp.asarray(insert_from, jnp.int32).reshape((-1, 1))
         page = jnp.where(positions >= ins, page, SCRATCH_PAGE)
     slot = jnp.broadcast_to(positions % ps, page.shape)
-    return _paged_write(cache, k, v, page, slot)
+    return _paged_write(cache, k, v, page, slot, layer)
 
 
-def gather_pages(pages, block_tables):
-    """Head-major pages (P, K, ps, ...) gathered to a position-ordered
-    per-row view (B, M * ps, K, ...)."""
-    g = jnp.swapaxes(pages[block_tables], 2, 3)   # (B, M, ps, K, ...)
+def _take_pages(pages, block_tables, layer):
+    """Block-table pages (B, M, K, ...) of one layer, read in place."""
+    if layer is None:
+        return pages[block_tables]
+    return pages[layer, block_tables]
+
+
+def _position_ordered(g):
+    """(B, M, K, ps, ...) gathered pages -> (B, M * ps, K, ...)."""
+    g = jnp.swapaxes(g, 2, 3)                     # (B, M, ps, K, ...)
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
-def paged_gather_kv(cache: Params, block_tables):
-    """Block-table gather of a paged cache -> (k, v) in compute
-    precision, (B, T, K, hd) with T = M * page_size (dequantized when
-    the pool stores int8)."""
-    k = gather_pages(cache["k"], block_tables)
-    v = gather_pages(cache["v"], block_tables)
+def gather_pages(pages, block_tables, layer=None):
+    """Head-major pages ([G,] P, K, ps, ...) of layer ``layer`` gathered
+    to a position-ordered per-row view (B, M * ps, K, ...)."""
+    return _position_ordered(_take_pages(pages, block_tables, layer))
+
+
+def paged_gather_kv(cache: Params, block_tables, layer=None):
+    """Block-table gather of a paged cache's layer ``layer`` -> (k, v)
+    in compute precision, (B, T, K, hd) with T = M * page_size
+    (dequantized when the pool stores int8)."""
+    k = gather_pages(cache["k"], block_tables, layer)
+    v = gather_pages(cache["v"], block_tables, layer)
     if k.dtype == jnp.int8:
-        # scales (P, K, 1, ps) gather like (P, K, ps, 1) pages
+        # scale rows (B, M, K, 1, ps) order like (B, M, K, ps, 1) pages
         def scales(name):
-            return gather_pages(jnp.swapaxes(cache[name], 2, 3), block_tables)
+            rows = _take_pages(cache[name], block_tables, layer)
+            return _position_ordered(jnp.swapaxes(rows, 3, 4))
         k = k.astype(jnp.bfloat16) * scales("k_scale")
         v = v.astype(jnp.bfloat16) * scales("v_scale")
     return k, v
@@ -636,11 +671,12 @@ def paged_decode_attention(q, cache: Params, block_tables, pos, *,
                            chunk: Optional[int] = None,
                            scale: Optional[float] = None,
                            logit_cap: Optional[float] = None,
-                           prefetch=None) -> jnp.ndarray:
+                           prefetch=None, layer=None) -> jnp.ndarray:
     """Single-token attention over a paged pool via per-row block tables.
 
     q: (B, 1, H, hd); block_tables: (B, M) int32 page ids; pos: (B,)
-    per-row query positions (each row's k/v already inserted).
+    per-row query positions (each row's k/v already inserted); ``layer``
+    the layer of a stacked pool to read.
     On TPU this lowers to the Pallas paged-attention kernel (block
     table scalar-prefetched, pages gathered page-by-page); elsewhere it
     runs the gather + shared-mask jnp path.  Returns (B, 1, H, vd).
@@ -652,9 +688,9 @@ def paged_decode_attention(q, cache: Params, block_tables, pos, *,
             q[:, 0], cache["k"], cache["v"], block_tables, lengths,
             window=window, chunk=chunk, scale=scale, logit_cap=logit_cap,
             k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
-            prefetch=prefetch)
+            prefetch=prefetch, layer=layer)
         return out[:, None]
-    k, v = paged_gather_kv(cache, block_tables)
+    k, v = paged_gather_kv(cache, block_tables, layer)
     k = shard(k, "batch", None, "kv_heads", None)
     v = shard(v, "batch", None, "kv_heads", None)
     kv_pos = jnp.arange(k.shape[1], dtype=jnp.int32)

@@ -68,7 +68,8 @@ def _project_q(params: Params, x, *, num_heads: int, d_nope: int, d_rope: int,
 def mla_prefill(params: Params, x, *, num_heads: int, q_lora: int, kv_lora: int,
                 d_nope: int, d_rope: int, v_head_dim: int, rope_theta: float,
                 positions, cache: Params = None, inner_remat: bool = False,
-                block_tables=None, q_offset=None, insert_from=None):
+                block_tables=None, q_offset=None, insert_from=None,
+                layer=None):
     """Training / prefill forward.  Returns (out (B,S,D), new_cache).
 
     ``q_offset`` (traced ok) switches to the shared-prefix *tail* path:
@@ -76,7 +77,8 @@ def mla_prefill(params: Params, x, *, num_heads: int, q_lora: int, kv_lora: int,
     positions q_offset.., then attention runs over the block-table
     gather of the pool (resident prefix latent + the tail), expanded to
     per-head K/V.  ``insert_from`` keeps writes off resident shared
-    pages (see attention.paged_cache_prefill).
+    pages (see attention.paged_cache_prefill).  ``layer`` addresses the
+    layer of a stacked paged pool.
     """
     del q_lora
     b, s, _ = x.shape
@@ -94,8 +96,9 @@ def mla_prefill(params: Params, x, *, num_heads: int, q_lora: int, kv_lora: int,
         latent = jnp.concatenate([c_kv, k_rope], axis=-1)[:, :, None, :]
         new_cache = paged_cache_prefill(cache, latent, latent[..., :1],
                                         block_tables, start=q_offset,
-                                        insert_from=insert_from)
-        lat = gather_pages(new_cache["k"], block_tables)[:, :, 0]   # (B,T,L)
+                                        insert_from=insert_from, layer=layer)
+        lat = gather_pages(new_cache["k"], block_tables,
+                           layer)[:, :, 0]                      # (B,T,L)
         t = lat.shape[1]
         c_g, kr_g = lat[..., :kv_lora], lat[..., kv_lora:]
         k_g = jnp.concatenate(
@@ -125,7 +128,8 @@ def mla_prefill(params: Params, x, *, num_heads: int, q_lora: int, kv_lora: int,
         latent = jnp.concatenate([c_kv, k_rope], axis=-1)[:, :, None, :]
         if block_tables is not None:
             new_cache = paged_cache_prefill(cache, latent, latent[..., :1],
-                                            block_tables, start=0)
+                                            block_tables, start=0,
+                                            layer=layer)
         else:
             new_cache = cache_prefill(cache, latent, latent[..., :1], start=0)
             new_cache = {"k": new_cache["k"], "v": new_cache["v"],
@@ -135,10 +139,12 @@ def mla_prefill(params: Params, x, *, num_heads: int, q_lora: int, kv_lora: int,
 
 def mla_decode(params: Params, x, cache: Params, pos, *, num_heads: int,
                kv_lora: int, d_nope: int, d_rope: int, v_head_dim: int,
-               rope_theta: float, block_tables=None, prefetch=None):
+               rope_theta: float, block_tables=None, prefetch=None,
+               layer=None):
     """Absorbed single-token decode.  cache['k']: (B, cap, 1, kv_lora+d_rope)
     (ring), or with ``block_tables`` (B, M) a paged latent pool
-    (P, 1, page_size, kv_lora+d_rope) with per-row positions ``pos`` (B,).
+    ([G,] P, 1, page_size, kv_lora+d_rope) read and written at layer
+    ``layer``, with per-row positions ``pos`` (B,).
 
     Returns (out (B,1,D), new_cache).
     """
@@ -154,7 +160,7 @@ def mla_decode(params: Params, x, cache: Params, pos, *, num_heads: int,
     latent = jnp.concatenate([c_kv, k_rope], axis=-1)[:, :, None, :]
     if block_tables is not None:
         cache = paged_cache_insert(cache, latent, latent[..., :1],
-                                   block_tables, pos)
+                                   block_tables, pos, layer)
     else:
         cache = cache_insert(cache, latent, latent[..., :1], pos)
 
@@ -173,9 +179,9 @@ def mla_decode(params: Params, x, cache: Params, pos, *, num_heads: int,
             out_lat = kops.paged_attention(
                 q_cat[:, 0], cache["k"], cache["k"], block_tables, lengths,
                 scale=1.0 / math.sqrt(d_nope + d_rope),
-                v_dim=kv_lora, prefetch=prefetch)[:, None]
+                v_dim=kv_lora, prefetch=prefetch, layer=layer)[:, None]
         else:
-            lat = gather_pages(cache["k"], block_tables)   # (B, T, 1, L)
+            lat = gather_pages(cache["k"], block_tables, layer)  # (B,T,1,L)
             out_lat = masked_decode_attention(
                 q_cat, lat, lat[..., :kv_lora],
                 jnp.arange(lat.shape[1], dtype=jnp.int32), pos,

@@ -217,9 +217,10 @@ def _attn_scale(cfg: ModelConfig) -> float:
 def _mixer_forward(p: Params, spec: LayerSpec, cfg: ModelConfig, x,
                    *, positions, mode: str, pos=None, cache=None,
                    image_embeds=None, block_tables=None, q_offset=None,
-                   insert_from=None, prefetch=None):
+                   insert_from=None, prefetch=None, layer=None):
     """Returns (out, new_cache).  ``block_tables`` (B, M) switches the
-    cache path to the paged pool; in decode mode ``pos`` is then a
+    cache path to the paged pool — the whole stacked pool, read and
+    written at layer group ``layer``; in decode mode ``pos`` is then a
     per-row (B,) vector rather than a shared scalar.  ``q_offset``
     (prefill mode, traced ok) is the shared-prefix tail path: K/V is
     written at absolute positions q_offset.. and attention runs over
@@ -245,13 +246,14 @@ def _mixer_forward(p: Params, spec: LayerSpec, cfg: ModelConfig, x,
         if mode == "decode":
             return mla_mod.mla_decode(p["attn"], x, cache, pos,
                                       block_tables=block_tables,
-                                      prefetch=prefetch, **kw)
+                                      prefetch=prefetch, layer=layer, **kw)
         return mla_mod.mla_prefill(p["attn"], x, q_lora=cfg.q_lora,
                                    positions=positions, cache=cache,
                                    inner_remat=inner_remat,
                                    block_tables=block_tables,
                                    q_offset=q_offset,
-                                   insert_from=insert_from, **kw)
+                                   insert_from=insert_from, layer=layer,
+                                   **kw)
 
     if spec.mixer == "cross_attn":
         ap = p["attn"]
@@ -298,11 +300,12 @@ def _mixer_forward(p: Params, spec: LayerSpec, cfg: ModelConfig, x,
     chunk = cfg.chunk if spec.attn_kind == "chunked" else None
     if mode == "decode":
         if block_tables is not None:
-            cache = attn.paged_cache_insert(cache, k, v, block_tables, pos)
+            cache = attn.paged_cache_insert(cache, k, v, block_tables, pos,
+                                            layer)
             out = attn.paged_decode_attention(
                 q, cache, block_tables, pos, window=window, chunk=chunk,
                 scale=_attn_scale(cfg), logit_cap=cfg.attn_logit_cap,
-                prefetch=prefetch)
+                prefetch=prefetch, layer=layer)
         else:
             cache = attn.cache_insert(cache, k, v, pos)
             out = attn.decode_attention(q, cache, pos, window=window,
@@ -315,10 +318,12 @@ def _mixer_forward(p: Params, spec: LayerSpec, cfg: ModelConfig, x,
         # resident prefix pages this request mapped plus its own tail
         new_cache = attn.paged_cache_prefill(cache, k, v, block_tables,
                                              start=q_offset,
-                                             insert_from=insert_from)
+                                             insert_from=insert_from,
+                                             layer=layer)
         out = attn.paged_prefill_attention(
             q, new_cache, block_tables, q_offset, window=window, chunk=chunk,
-            scale=_attn_scale(cfg), logit_cap=cfg.attn_logit_cap)
+            scale=_attn_scale(cfg), logit_cap=cfg.attn_logit_cap,
+            layer=layer)
     else:
         out = attn.blocked_attention(q, k, v, causal=True, window=window,
                                      chunk=chunk, scale=_attn_scale(cfg),
@@ -328,7 +333,8 @@ def _mixer_forward(p: Params, spec: LayerSpec, cfg: ModelConfig, x,
         if cache is not None:
             if block_tables is not None:
                 new_cache = attn.paged_cache_prefill(cache, k, v,
-                                                     block_tables, start=0)
+                                                     block_tables, start=0,
+                                                     layer=layer)
             else:
                 new_cache = attn.cache_prefill(cache, k, v, start=0)
     return attn.out_project(ap, out), new_cache
@@ -337,7 +343,7 @@ def _mixer_forward(p: Params, spec: LayerSpec, cfg: ModelConfig, x,
 def _block_forward(p: Params, spec: LayerSpec, cfg: ModelConfig, h,
                    *, positions, mode: str, pos=None, cache=None,
                    image_embeds=None, block_tables=None, q_offset=None,
-                   insert_from=None, prefetch=None):
+                   insert_from=None, prefetch=None, layer=None):
     """One transformer block.  Returns (h, new_cache, aux_loss)."""
     gated_residual = spec.mixer == "cross_attn"
     mix_in = apply_norm(p["norm1"], h, cfg.norm, cfg.norm_eps)
@@ -346,7 +352,7 @@ def _block_forward(p: Params, spec: LayerSpec, cfg: ModelConfig, h,
                                     image_embeds=image_embeds,
                                     block_tables=block_tables,
                                     q_offset=q_offset, insert_from=insert_from,
-                                    prefetch=prefetch)
+                                    prefetch=prefetch, layer=layer)
     # Megatron-SP: constrain the row-parallel output to the seq-sharded
     # layout BEFORE the residual add so XLA emits a reduce-scatter
     # instead of all-reduce + reshard (2x+ the link bytes); §Perf iter
@@ -415,28 +421,48 @@ def unembed(params: Params, cfg: ModelConfig, h):
 def _scan_blocks(params: Params, cfg: ModelConfig, h, *, positions, mode: str,
                  pos=None, caches=None, image_embeds=None, block_tables=None,
                  q_offset=None, insert_from=None, prefetch=None):
-    """Scan over the G pattern groups.  Returns (h, new_caches, aux_sum)."""
+    """Scan over the G pattern groups.  Returns (h, new_caches, aux_sum).
+
+    Ring caches (and training, with none) ride the scan's ``xs``/``ys``
+    one group slice at a time.  A paged pool (``block_tables`` given)
+    rides the carry whole instead, with the group index in ``xs``: each
+    layer writes and reads its pages at that index, so the pool is
+    updated in place and never sliced out or restacked."""
     specs = cfg.pattern
+
+    def blocks(block_params, hh, group_caches, layer):
+        aux_g = jnp.zeros((), jnp.float32)
+        new_caches = {}
+        for i, spec in enumerate(specs):
+            c = None if group_caches is None else group_caches.get(f"p{i}")
+            hh, nc, aux = _block_forward(
+                block_params[f"p{i}"], spec, cfg, hh, positions=positions,
+                mode=mode, pos=pos, cache=c, image_embeds=image_embeds,
+                block_tables=block_tables, q_offset=q_offset,
+                insert_from=insert_from, prefetch=prefetch, layer=layer)
+            aux_g = aux_g + aux
+            if nc is not None:
+                new_caches[f"p{i}"] = nc
+        return hh, aux_g, new_caches
+
+    aux0 = jnp.zeros((), jnp.float32)
+    if block_tables is not None and caches is not None:
+        def layer_fn(carry, xs):
+            hh, aux_acc, pool = carry
+            block_params, g = xs
+            hh, aux_g, pool = blocks(block_params, hh, pool, g)
+            return (hh, aux_acc + aux_g, pool), None
+
+        xs = (params["blocks"], jnp.arange(cfg.num_groups, dtype=jnp.int32))
+        (h, aux, caches), _ = jax.lax.scan(layer_fn, (h, aux0, caches), xs)
+        return h, caches, aux
 
     def group_fn(carry, xs):
         hh, aux_acc = carry
         block_params, group_caches = xs
 
         def body(hh):
-            aux_g = jnp.zeros((), jnp.float32)
-            new_caches = {}
-            for i, spec in enumerate(specs):
-                c = None if group_caches is None else group_caches.get(f"p{i}")
-                hh2, nc, aux = _block_forward(
-                    block_params[f"p{i}"], spec, cfg, hh, positions=positions,
-                    mode=mode, pos=pos, cache=c, image_embeds=image_embeds,
-                    block_tables=block_tables, q_offset=q_offset,
-                    insert_from=insert_from, prefetch=prefetch)
-                hh = hh2
-                aux_g = aux_g + aux
-                if nc is not None:
-                    new_caches[f"p{i}"] = nc
-            return hh, aux_g, new_caches
+            return blocks(block_params, hh, group_caches, None)
 
         if cfg.remat in ("full", "full_inner") and mode == "train":
             body = jax.checkpoint(body)
@@ -444,7 +470,7 @@ def _scan_blocks(params: Params, cfg: ModelConfig, h, *, positions, mode: str,
         return (hh, aux_acc + aux_g), (new_caches or None)
 
     xs = (params["blocks"], caches)
-    (h, aux), out_caches = jax.lax.scan(group_fn, (h, jnp.zeros((), jnp.float32)), xs)
+    (h, aux), out_caches = jax.lax.scan(group_fn, (h, aux0), xs)
     return h, out_caches, aux
 
 

@@ -112,11 +112,69 @@ def test_paged_attention_int8_dequant_in_kernel():
 
 def _quantize_pages(pages):
     """Per-(slot, head) symmetric int8 + bf16 scales, like the pool's:
-    pages (P, K, ps, hd) -> scales (P, K, 1, ps)."""
+    pages ([L,] P, K, ps, hd) -> scales ([L,] P, K, 1, ps)."""
     sc = np.abs(np.asarray(pages)).max(axis=-1) / 127.0 + 1e-8
     qp = np.clip(np.round(np.asarray(pages) / sc[..., None]), -127, 127)
     return (jnp.asarray(qp, jnp.int8),
-            jnp.asarray(sc[:, :, None, :], jnp.bfloat16))
+            jnp.asarray(sc[..., None, :], jnp.bfloat16))
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("variant", ["bf16", "int8", "mla_vdim"])
+def test_paged_attention_stacked_pool_reads_layer(variant, layer):
+    """A stacked (L, P, K, ps, hd) pool read at ``layer`` (one more
+    scalar-prefetch operand) attends that layer's pages alone: the
+    oracle on pages[layer], with either scalar encoding, for bf16 pages,
+    int8 pages with their scale slabs, and MLA's v_dim slice."""
+    from repro.kernels.paged_attention import decode_prefetch
+    n_layers, b, h, kk, hd, ps, m = 3, 3, 4, 2, 16, 8, 4
+    v_dim = None
+    if variant == "mla_vdim":
+        kk, hd, v_dim = 1, 24, 16
+    pages = 1 + b * m
+    kq, kp, kv = jax.random.split(jax.random.fold_in(KEY, layer), 3)
+    q = jax.random.normal(kq, (b, h, hd))
+    k_pages = jax.random.normal(kp, (n_layers, pages, kk, ps, hd))
+    v_pages = (k_pages if variant == "mla_vdim"
+               else jax.random.normal(kv, (n_layers, pages, kk, ps, hd)))
+    ks = vs = None
+    k_want, v_want = k_pages[layer], v_pages[layer]
+    if variant == "int8":
+        k_pages, ks = _quantize_pages(k_pages)
+        v_pages, vs = _quantize_pages(v_pages)
+        def dequant(x, sc):
+            return (x.astype(jnp.float32)
+                    * jnp.swapaxes(sc, -1, -2).astype(jnp.float32))
+        k_want = dequant(k_pages[layer], ks[layer])
+        v_want = dequant(v_pages[layer], vs[layer])
+    if v_dim is not None:
+        v_want = v_want[..., :v_dim]
+    perm = np.stack([np.random.RandomState(i).permutation(pages - 1)[:m] + 1
+                     for i in range(b)])
+    bt = jnp.asarray(perm, jnp.int32)
+    lengths = jnp.asarray([5, 17, 32], jnp.int32)
+    want = ref.paged_attention_ref(q, k_want, v_want, bt, lengths)
+    for pf in (None, decode_prefetch(bt, lengths)):
+        out = paged_attention(q, k_pages, v_pages, bt, lengths, k_scales=ks,
+                              v_scales=vs, v_dim=v_dim, prefetch=pf,
+                              layer=jnp.asarray(layer, jnp.int32),
+                              interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5)
+
+
+def test_paged_attention_layer_matches_pool_rank():
+    """A stacked pool needs a layer index and a single layer's pool
+    takes none: a mismatch is refused, not read at the wrong layer."""
+    q = jnp.zeros((1, 2, 16))
+    bt = jnp.ones((1, 2), jnp.int32)
+    ln = jnp.ones((1,), jnp.int32)
+    stacked = jnp.zeros((2, 3, 2, 8, 16))
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, stacked, stacked, bt, ln, interpret=True)
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, stacked[0], stacked[0], bt, ln, layer=0,
+                        interpret=True)
 
 
 _GROUP_VARIANTS = {

@@ -136,6 +136,67 @@ def test_paged_matches_ring_per_step(variant):
                                    atol=3e-5, err_msg=f"{variant} pos={i}")
 
 
+@pytest.mark.parametrize("variant", ["full", "mla"])
+def test_stacked_pool_chunked_prefill_and_decode_match_ring(variant):
+    """Three layers over one stacked pool, carried through the layer
+    scan: a prompt prefilled in two chunks (the whole-prompt program,
+    then the tail program) and decoded matches the ring path, and each
+    decode step changes every layer's pool only at the (page, slot)
+    rows of the step's token."""
+    from repro.models import attention as attn
+    cfg = tiny_config(variant).with_(num_layers=3)
+    assert cfg.num_groups == 3
+    key = jax.random.key(5)
+    params = tf.init_params(cfg, key)
+    b, s, c, p, ps = 1, 20, 6, 11, 4
+    tokens = jax.random.randint(key, (b, s), 0, cfg.vocab_size)
+    logits_r, ring = tf.prefill(params, cfg, tokens[:, :p], cache_len=s,
+                                cache_dtype=jnp.float32)
+    m = -(-s // ps)
+    paged = tf.init_caches(cfg, 0, 0, jnp.float32, num_pages=m + 2,
+                           page_size=ps)
+    bt = jnp.asarray([[3, 1, 4, 2, 6]], jnp.int32)[:, :m]
+    _, paged = tf.prefill_paged(params, cfg, tokens[:, :c], paged, bt,
+                                last_index=c - 1)
+    c_ = jnp.asarray(c, jnp.int32)
+    logits_p, paged = tf.prefill_paged(params, cfg, tokens[:, c:p], paged,
+                                       bt, last_index=p - c - 1,
+                                       q_offset=c_, insert_from=c_)
+    np.testing.assert_allclose(np.asarray(logits_r), np.asarray(logits_p),
+                               atol=3e-5)
+    for i in range(p, s):
+        before = jax.tree.map(np.asarray, paged)
+        logits_r, ring = tf.decode_step(params, cfg, tokens[:, i:i + 1],
+                                        ring, i)
+        logits_p, paged = tf.decode_step(params, cfg, tokens[:, i:i + 1],
+                                         paged, jnp.asarray([i]),
+                                         block_tables=bt)
+        np.testing.assert_allclose(np.asarray(logits_r), np.asarray(logits_p),
+                                   atol=3e-5, err_msg=f"{variant} pos={i}")
+        page, slot = int(bt[0, i // ps]), i % ps
+        for name, leaf in paged["p0"].items():
+            now = np.asarray(leaf)                 # (G, P, K, ps, hd)
+            changed = np.argwhere(now != before["p0"][name])
+            assert len(changed), (name, i)
+            assert set(map(tuple, changed[:, [1, 3]])) == {(page, slot)}
+            assert set(changed[:, 0]) == {0, 1, 2}
+
+    # one layer's write leaves the other layers bit-for-bit alone
+    pool = paged["p0"]
+    k_new, v_new = (jnp.ones((2, 1) + pool[n].shape[-3::2], jnp.float32)
+                    for n in ("k", "v"))                # (B, 1, K, width)
+    wrote = attn.paged_cache_insert(pool, k_new, v_new,
+                                    jnp.asarray([[2], [5]]),
+                                    jnp.asarray([1, 3]), layer=1)
+    for name in pool:
+        old, new = np.asarray(pool[name]), np.asarray(wrote[name])
+        np.testing.assert_array_equal(old[[0, 2]], new[[0, 2]])
+        rows = np.argwhere(old[1] != new[1])
+        assert set(map(tuple, rows[:, [0, 2]])) <= {(2, 1), (5, 3)}
+        np.testing.assert_array_equal(new[1, 2, :, 1], 1.0)
+        np.testing.assert_array_equal(new[1, 5, :, 3], 1.0)
+
+
 def test_mixed_length_batch_matches_solo():
     """Two requests at different positions decode in ONE paged batch;
     each row matches the same request decoded alone (token-level

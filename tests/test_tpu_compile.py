@@ -5,10 +5,15 @@ for one chip of a ``v5e:2x2`` topology and compiles it with the TPU
 compiler, so a tiling or VMEM refusal shows up here instead of on the
 chip.  Each compiled program must hold the Mosaic kernel
 (``tpu_custom_call``), i.e. no case silently fell back to plain XLA.
-The topology is described inside a fixture, never at import time: only
-one process at a time may load the TPU compiler's library.
+The olmo-1b paged programs are compiled whole, pool donated, to pin
+that the stacked KV pool is updated in place: no pool-sized copy and
+almost no temporaries.  The topology is described inside a fixture,
+never at import time: only one process at a time may load the TPU
+compiler's library.
 """
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +28,11 @@ BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
 
 # olmo-1b decode: B=8, 16/16 heads of 128, page 64, 512 pages, 32 pages/row
 B, PS, PAGES, M = 8, 64, 512, 32
+
+# olmo-1b as served: 16 layers, 512 pages of 64 tokens, 16 kv heads of
+# 128, decode batch 32, block tables 32 pages wide, chunks of 256 tokens
+OLMO_POOL = (16, 512, 16, 64, 128)
+OLMO_PROGRAMS = ("decode", "prefill_tail", "prefill")
 
 
 @pytest.fixture(scope="module")
@@ -73,6 +83,23 @@ def test_paged_attention_compiles_for_v5e(one_chip, case):
     assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
 
 
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_stacked_paged_attention_compiles_for_v5e(one_chip, case):
+    """The same kernels reading one layer of a 16-layer stacked pool,
+    the layer index a traced scalar-prefetch operand."""
+    h, kv, hd, dt, v_dim = PAGED_CASES[case]
+    layers = OLMO_POOL[0]
+    shapes = [((B, h, hd), BF16), ((layers, PAGES, kv, PS, hd), dt),
+              ((layers, PAGES, kv, PS, hd), dt), ((B, M), I32), ((B,), I32),
+              ((), I32)]
+    if dt == I8:
+        shapes += [((layers, PAGES, kv, 1, PS), BF16)] * 2
+
+    def fn(q, k, v, bt, ln, layer, *scales):
+        return _paged(q, k, v, bt, ln, *scales, v_dim=v_dim, layer=layer)
+    assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
+
+
 def test_mux_score_compiles_for_v5e(one_chip):
     shapes = [((8, 512), F32), ((3, 512), F32), ((3,), F32)]
     assert "tpu_custom_call" in _compiled_text(mux_score, shapes, one_chip)
@@ -82,3 +109,109 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     shapes = [((1, 2048, 16, 128), BF16)] * 3
     assert "tpu_custom_call" in _compiled_text(flash_attention, shapes,
                                                one_chip)
+
+
+# "%name = <shape> opcode(operands...), attributes"; a tuple shape holds
+# spaces, but no " word(" — only the opcode is a word right before "("
+_INSTR = re.compile(r"^\s*(ROOT )?%(\S+) = (.*?) ([a-z][\w-]*)\((.*)$")
+
+
+@pytest.fixture(scope="module")
+def olmo_compiled(one_chip):
+    """The paged decode step, tail prefill and whole-prompt prefill of
+    olmo-1b at full width, compiled as the engine jits them (pool
+    donated) with the Pallas path on, as on the chip."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import transformer as tf
+
+    cfg = get_config("olmo-1b")
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    params = jax.tree.map(place, tf.abstract_params(cfg, "bfloat16"))
+    caches = jax.tree.map(place, tf.abstract_caches(
+        cfg, 0, 0, BF16, num_pages=OLMO_POOL[1], page_size=OLMO_POOL[3]))
+    b, m, s = 32, 32, 256
+
+    def decode(p, token, caches, bt, pos):
+        return tf.decode_step(p, cfg, token, caches, pos, block_tables=bt)
+
+    def prefill_tail(p, tokens, caches, bt, last, q_offset, insert_from):
+        return tf.prefill_paged(p, cfg, tokens, caches, bt, last,
+                                q_offset=q_offset, insert_from=insert_from)
+
+    def prefill(p, tokens, caches, bt, last):
+        return tf.prefill_paged(p, cfg, tokens, caches, bt, last)
+
+    args = {
+        "decode": (decode, (params, i32(b, 1), caches, i32(b, m), i32(b))),
+        "prefill_tail": (prefill_tail, (params, i32(1, s), caches,
+                                        i32(1, m), i32(), i32(), i32())),
+        "prefill": (prefill, (params, i32(1, s), caches, i32(1, m), i32())),
+    }
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_FORCE", True)
+        mp.setattr(ops, "_interpret", lambda: False)
+        for name, (fn, a) in args.items():
+            out[name] = jax.jit(fn, donate_argnums=(2,)).lower(*a).compile()
+    return out
+
+
+def _pool_sized_ops(hlo: str):
+    """(name, opcode) of every bf16 instruction with as many elements as
+    the stacked pool or one layer of it, in any shape of rows of
+    head_dim (so not the stacked (16, 2048, 2048) projections), that is
+    not a parameter, a loop tuple element, a bitcast or an in-place
+    scatter (a scatter, or a fusion whose root is one)."""
+    sizes = {math.prod(OLMO_POOL), math.prod(OLMO_POOL[1:])}
+    roots, instrs, comp = {}, [], None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        ins = _INSTR.match(line)
+        if head:
+            comp = head.group(1)
+        elif ins:
+            root, name, shape, op, rest = ins.groups()
+            if root:
+                roots[comp] = op
+            instrs.append((name, shape, op, rest))
+    bad = []
+    for name, shape, op, rest in instrs:
+        dims = re.match(r"bf16\[([\d,]+)\]", shape)
+        dims = [int(d) for d in dims.group(1).split(",")] if dims else [0]
+        if math.prod(dims) not in sizes or dims[-1] != OLMO_POOL[-1]:
+            continue
+        if op in ("parameter", "get-tuple-element", "bitcast", "scatter"):
+            continue
+        called = re.search(r"calls=%([^,\s]+)", rest)
+        if op == "fusion" and called and roots.get(called.group(1)) == \
+                "scatter":
+            continue
+        bad.append((name, op))
+    return bad
+
+
+@pytest.mark.parametrize("program", OLMO_PROGRAMS)
+def test_olmo_paged_program_temporaries_are_small(olmo_compiled, program):
+    """The pool (4 GiB) is updated in place: under 256 MiB of
+    temporaries, where slicing each layer out of the stacked pool and
+    restacking it took 4.8-5.4 GB."""
+    temp = olmo_compiled[program].memory_analysis().temp_size_in_bytes
+    assert temp < 256 * 2**20, temp
+
+
+@pytest.mark.parametrize("program", OLMO_PROGRAMS)
+def test_olmo_paged_program_copies_no_pool(olmo_compiled, program):
+    """No operation but the in-place scatters and the loop's own tuple
+    and parameters produces the stacked pool or a layer slice of it."""
+    assert _pool_sized_ops(olmo_compiled[program].as_text()) == []
+
+
+def test_olmo_paged_decode_holds_kernel(olmo_compiled):
+    assert "tpu_custom_call" in olmo_compiled["decode"].as_text()
