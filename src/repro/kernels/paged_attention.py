@@ -16,19 +16,36 @@ index, so the BlockSpec index_map can steer the K/V DMA to the right
 (layer, page) before the kernel body runs — the gather never
 materialises a contiguous per-row KV copy in HBM.
 
-Grid: (batch, kv_heads, num_pages_per_row) with a (g, hd) query block,
-where g = q_heads // kv_heads is the GQA group size.  Each K/V page is
-DMA'd **once per group** and the score / PV matmuls are (g, page_size)-
-shaped — decode HBM traffic, the thing decode is bound on, is cut g-fold
-versus gridding over query heads (``grouped=False`` keeps the per-head
-grid as a measurable baseline; there every group member re-fetches the
-same page).  The trailing grid dimension is sequential on TPU, so the
-online-softmax running state (m, l, acc) lives in VMEM scratch and is
-carried across a row's pages, exactly like the flash kernel carries it
-across KV blocks.  Pages past a row's length (and outside its
-window/chunk span) are skipped with pl.when on the *dynamic* per-row
-length — short rows in a mixed-length decode batch do proportionally
-less work, which is the point of paging.
+Grid: (batch, num_pages_per_row).  One step moves one page of every kv
+head: a (1, K, page_size, hd) block of K and one of V, a contiguous
+slab of the head-major pool, against a (1, K, g, hd) query block, where
+g = q_heads // kv_heads is the GQA group size.  Each page is DMA'd
+**once per row** for all K heads and all g group members, and a layer
+takes batch x pages steps, not batch x K x pages: at olmo-1b's decode
+shape (32 rows, 32 pages, 16 heads) 1 024 steps of 512 KiB of K+V
+instead of 16 384 of 32 KiB, so a step's fixed cost stops setting the
+kernel's time.  The step body runs the per-(head, page) arithmetic for
+each of the K heads in turn (a static loop; the same f32 casts, (g,
+page_size) score and PV matmuls, masks and online softmax), so the
+output is bitwise that of one head per step.  ``grouped=False`` keeps
+a per-head grid, (batch, q_heads, num_pages_per_row) with one head per
+block, as a measurable baseline: there every group member re-fetches
+the same page.  The trailing (page) grid dimension is sequential on
+TPU, so the online-softmax running state (m, l, acc) of every head of
+the block lives in VMEM scratch, (K, g) and (K, g, vd), and is carried
+across a row's pages, exactly like the flash kernel carries it across
+KV blocks.
+
+Pages past a row's length (and outside its window/chunk span) are dead:
+pl.when on the *dynamic* per-row length skips their compute, and the
+K/V index maps clamp each page step into the row's live span
+(:func:`_live_span`), so a dead step names the same page as its live
+neighbour and the pipeline, which fetches a block only when its index
+changes, issues no DMA for it.  Reserved but unwritten pages at the end
+of a block-table row, the pages before a window, and every page of a
+padded row but its first are never read — short rows in a mixed-length
+decode batch move proportionally fewer bytes, which is the point of
+paging.
 
 When the pool stores int8, per-(slot, head) bf16 scales ride along as
 two more page slabs of shape ([G,] num_pages, K, 1, page_size) — one
@@ -43,9 +60,9 @@ quantized width.
 (B, M+1) int32 scalar operand that the caller builds once per decode
 step and shares across every layer, so the per-layer scalar-prefetch
 setup amortizes over the stack instead of re-staging two operands per
-layer.  :func:`decode_hbm_bytes` is the analytic mirror of the grid —
-the deterministic K/V byte count benchmarks and the roofline report
-use, so the g-fold claim is measured, not asserted.
+layer.  :func:`decode_hbm_bytes` counts the K/V bytes the grid moves —
+the deterministic count benchmarks and the roofline report use, so the
+g-fold claim is measured, not asserted.
 """
 from __future__ import annotations
 
@@ -61,10 +78,29 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
+def _live_span(length, *, page_size: int, window: Optional[int],
+               chunk: Optional[int], last_step: int):
+    """First and last page step of a row's live span: the pages the
+    liveness test of :func:`_paged_attn_kernel` keeps, for a row with
+    ``length`` visible tokens.  A row with no live page (length 0) gets
+    the span (0, 0).  Scalar arithmetic on traced int32s, shared by the
+    K/V index maps."""
+    q_pos = jnp.maximum(length - 1, 0)
+    last = jnp.minimum(q_pos // page_size, last_step)
+    first = jnp.zeros_like(last)
+    if window is not None:      # first visible position q_pos - window + 1
+        first = jnp.maximum(first,
+                            jnp.maximum(q_pos - window + 1, 0) // page_size)
+    if chunk is not None:       # the chunk floor (q_pos // chunk) * chunk
+        first = jnp.maximum(first, (q_pos // chunk) * chunk // page_size)
+    return jnp.minimum(first, last), last
+
+
 def _paged_attn_kernel(*refs, scale: float, window: Optional[int],
                        chunk: Optional[int], logit_cap: Optional[float],
                        page_size: int, quantized: bool,
-                       length_col: Optional[int], num_scalars: int):
+                       length_col: Optional[int], num_scalars: int,
+                       heads: int, page_axis: int):
     # scalar refs: block tables, then lengths unless they ride in the
     # combined prefetch's last column, then the layer index if stacked
     bt_ref = refs[0]
@@ -76,8 +112,8 @@ def _paged_attn_kernel(*refs, scale: float, window: Optional[int],
         o_ref, m_scr, l_scr, acc_scr = rest
         ks_ref = vs_ref = None
     b = pl.program_id(0)
-    i = pl.program_id(2)
-    nm = pl.num_programs(2)
+    i = pl.program_id(page_axis)
+    nm = pl.num_programs(page_axis)
 
     @pl.when(i == 0)
     def _init():
@@ -98,15 +134,15 @@ def _paged_attn_kernel(*refs, scale: float, window: Optional[int],
     if chunk is not None:
         live &= k_last >= (q_pos // chunk) * chunk
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale         # (G, hd)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (ps, hd)
-        v = v_ref[0, 0].astype(jnp.float32)                 # (ps, vd)
+    def attend(h):
+        """One kv head's (G, page_size) online-softmax update."""
+        q = q_ref[0, h].astype(jnp.float32) * scale         # (G, hd)
+        k = k_ref[0, h].astype(jnp.float32)                 # (ps, hd)
+        v = v_ref[0, h].astype(jnp.float32)                 # (ps, vd)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if quantized:                                       # (1, ps) rows
-            sc = sc * ks_ref[0, 0].astype(jnp.float32)
+            sc = sc * ks_ref[0, h].astype(jnp.float32)
         if logit_cap is not None:
             sc = jnp.tanh(sc / logit_cap) * logit_cap
         kv_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
@@ -117,24 +153,29 @@ def _paged_attn_kernel(*refs, scale: float, window: Optional[int],
             mask &= kv_pos >= (q_pos // chunk) * chunk
         sc = jnp.where(mask, sc, NEG_INF)
 
-        m_prev = m_scr[...]
+        m_prev = m_scr[h]
         m_new = jnp.maximum(m_prev, sc.max(axis=1))
         p = jnp.exp(sc - m_new[:, None])
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        pv = p * vs_ref[0, 0].astype(jnp.float32) if quantized else p
-        acc_scr[...] = (acc_scr[...] * corr[:, None]
-                        + jax.lax.dot_general(pv, v, (((1,), (0,)), ((), ())),
-                                              preferred_element_type=jnp.float32))
-        m_scr[...] = m_new
+        l_scr[h] = l_scr[h] * corr + p.sum(axis=1)
+        pv = p * vs_ref[0, h].astype(jnp.float32) if quantized else p
+        acc_scr[h] = (acc_scr[h] * corr[:, None]
+                      + jax.lax.dot_general(pv, v, (((1,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32))
+        m_scr[h] = m_new
+
+    @pl.when(live)
+    def _compute():
+        for h in range(heads):
+            attend(h)
 
     @pl.when(i == nm - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
         # a row with length == 0 never enters _compute: acc / clamped-l
         # is not attention over anything — the contract is exact zeros
-        out = jnp.where(length > 0, acc_scr[...] / l[:, None], 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        out = jnp.where(length > 0, acc_scr[...] / l[..., None], 0.0)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def decode_prefetch(block_tables, lengths):
@@ -158,9 +199,14 @@ def decode_hbm_bytes(k_pages, v_pages, block_tables, lengths, *,
                      quantized: Optional[bool] = None,
                      grouped: bool = True) -> int:
     """Analytic K/V HBM bytes one :func:`paged_attention` call DMAs —
-    a deterministic host-side mirror of the kernel's grid and per-page
-    liveness test (length / window / chunk), counting only page (and
-    scale-slab) traffic, the term decode is bandwidth-bound on.
+    a deterministic host-side count of the live pages (the kernel's
+    per-page liveness test: length / window / chunk) times the page
+    bytes of K and V (and of the scale slabs), the term decode is
+    bandwidth-bound on.  The index maps clamp every dead step onto a
+    live neighbour, so the grid fetches each live page once and no
+    dead one; the count leaves out only the one page a row with no
+    live page (length 0) still names, fetched when that row's block
+    index differs from the previous row's.
 
     grouped=True counts one K/V fetch per (row, kv_head, live page);
     grouped=False counts one per (row, q_head, live page) — the exact
@@ -211,9 +257,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     serves absorbed-MLA decode, where v is the latent's first kv_lora
     features of the same slab, without a second page store.
 
-    ``grouped`` grids over KV heads with a (g, hd) query block (each
-    page fetched once per GQA group); False keeps the per-head grid as
-    the bandwidth baseline.  ``prefetch`` accepts the combined
+    ``grouped`` grids over (row, page) with every kv head's page in one
+    block and a (K, g, hd) query block (each page fetched once per row);
+    False keeps the per-head grid, one head per step, as the bandwidth
+    baseline.  ``prefetch`` accepts the combined
     (B, M+1) operand from :func:`decode_prefetch`, replacing the
     separate block-table + lengths scalar operands.
     Returns (B, H, vd) in q.dtype; rows with length 0 are exact zeros.
@@ -233,18 +280,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     scale_ = scale if scale is not None else 1.0 / math.sqrt(hd)
     quantized = k_pages.dtype == jnp.int8
 
-    # grouped: grid over KV heads, the g query heads of the group ride in
-    # one (1, 1, g, hd) block and the page is DMA'd once for all of them;
-    # per-head: grid over q heads (G=1), each group member re-fetches it
+    # grouped: grid (row, page), every kv head of the page in one
+    # (1, K, ps, hd) block and the g query heads of each kv head in a
+    # (1, K, g, hd) query block, so a page is DMA'd once for all of them;
+    # per-head: grid (row, q head, page), one head per block (G=1), each
+    # group member re-fetching the page
     G = g if grouped else 1
     nh = kk if grouped else h
+    heads = kk if grouped else 1
     qg = q.reshape(b, nh, G, hd)        # head h <-> (h // g, h % g)
-    if grouped:
-        def kv_head(h_):
-            return h_
-    else:
-        def kv_head(h_):
-            return h_ // g
 
     if prefetch is not None:
         if prefetch.shape != (b, m + 1):
@@ -257,22 +301,35 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     if stacked:
         scalars.append(jnp.asarray(layer, jnp.int32).reshape((1,)))
 
-    # the first scalar ref is always the block table (or the combined
-    # prefetch, whose leading M columns are the block table); a stacked
-    # pool's layer index is the last
-    def q_idx(b_, h_, i, *refs):
-        return (b_, h_, 0, 0)
+    # index maps see the grid indices, (row, [head,] page step), then the
+    # scalar refs: the block table (or the combined prefetch, whose
+    # leading M columns are the block table and whose last is the
+    # length), the lengths if separate, and a stacked pool's layer index
+    ns = len(scalars)
 
-    def kv_idx(b_, h_, i, *refs):
-        at = (refs[0][b_, i], kv_head(h_), 0, 0)
+    def q_idx(*args):
+        grid = args[:-ns]
+        return (grid[0], 0 if grouped else grid[1], 0, 0)
+
+    def kv_idx(*args):
+        grid, refs = args[:-ns], args[-ns:]
+        b_, i = grid[0], grid[-1]
+        length = refs[1][b_] if length_col is None else refs[0][b_, length_col]
+        # a step outside the row's live span names the span's nearest
+        # page: consecutive dead steps then repeat one block index and
+        # the pipeline issues no DMA for them
+        first, last = _live_span(length, page_size=ps, window=window,
+                                 chunk=chunk, last_step=m - 1)
+        at = (refs[0][b_, jnp.clip(i, first, last)],
+              0 if grouped else grid[1] // g, 0, 0)
         return (refs[-1][0],) + at if stacked else at
 
     kernel = functools.partial(
         _paged_attn_kernel, scale=scale_, window=window, chunk=chunk,
         logit_cap=logit_cap, page_size=ps, quantized=quantized,
-        length_col=length_col, num_scalars=len(scalars))
+        length_col=length_col, num_scalars=ns, heads=heads,
+        page_axis=1 if grouped else 2)
 
-    # index maps see the grid indices then the scalar-prefetch ref(s);
     # the (layer,) page id for (row b, step i) steers the K/V (and
     # scale) DMAs.  Every block's two minor dims are (ps, hd|vd) or
     # (1, ps): whole tiles of the head-major pool, as the TPU's tiling
@@ -280,9 +337,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     lead = (pl.Squeezed(),) if stacked else ()
 
     def kv_spec(*tile):
-        return pl.BlockSpec(lead + (1, 1) + tile, kv_idx)
+        return pl.BlockSpec(lead + (1, heads) + tile, kv_idx)
 
-    in_specs = [pl.BlockSpec((1, 1, G, hd), q_idx),
+    in_specs = [pl.BlockSpec((1, heads, G, hd), q_idx),
                 kv_spec(ps, hd), kv_spec(ps, vd)]
     args = [qg, k_pages, v_pages]
     if quantized:
@@ -291,13 +348,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, nh, m),
+        grid=(b, m) if grouped else (b, h, m),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, vd), q_idx),
+        out_specs=pl.BlockSpec((1, heads, G, vd), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, vd), jnp.float32),
+            pltpu.VMEM((heads, G), jnp.float32),
+            pltpu.VMEM((heads, G), jnp.float32),
+            pltpu.VMEM((heads, G, vd), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -270,6 +270,75 @@ def test_paged_combined_prefetch_matches_separate_operands():
         assert np.array_equal(np.asarray(sep), np.asarray(comb))
 
 
+@pytest.mark.parametrize("variant", ["full", "window"])
+@pytest.mark.parametrize("g", [1, 4])
+def test_paged_dead_pages_never_reach_the_output(g, variant):
+    """Block-table entries outside a row's live span (past its length,
+    before its window, every entry of a length-0 row) name pages filled
+    with NaN: the output is finite and bitwise equal to the same call
+    with those entries naming a page of zeros."""
+    from repro.kernels.paged_attention import decode_prefetch
+    b, kk, hd, ps, m = 4, 2, 16, 8, 5
+    h = kk * g
+    kw = _GROUP_VARIANTS[variant]
+    window = kw.get("window")
+    lengths = np.asarray([0, 5, 23, 30], np.int32)
+    nan_page = 1 + b * m
+    kq, kp, kv = jax.random.split(jax.random.fold_in(KEY, g), 3)
+    q = jax.random.normal(kq, (b, h, hd), jnp.bfloat16)
+    # page 0 holds zeros, the last page NaN, the rest random
+    shape = (nan_page + 1, kk, ps, hd)
+    k_pages = jax.random.normal(kp, shape, jnp.bfloat16)
+    v_pages = jax.random.normal(kv, shape, jnp.bfloat16)
+    k_pages = k_pages.at[0].set(0).at[-1].set(jnp.nan)
+    v_pages = v_pages.at[0].set(0).at[-1].set(jnp.nan)
+    bt = np.arange(1, nan_page).reshape(b, m)
+    dead = np.zeros((b, m), bool)
+    for r, length in enumerate(lengths.tolist()):
+        for i in range(m):
+            live = i * ps < length
+            if window is not None:
+                live &= i * ps + ps - 1 > length - 1 - window
+            dead[r, i] = not live
+    assert dead[0].all() and dead.any(axis=1).all()
+    outs = {}
+    for fill in (0, nan_page):
+        tab = jnp.asarray(np.where(dead, fill, bt), jnp.int32)
+        ln = jnp.asarray(lengths)
+        outs[fill] = np.asarray(paged_attention(
+            q, k_pages, v_pages, tab, ln, prefetch=decode_prefetch(tab, ln),
+            interpret=True, **kw), np.float32)
+    assert np.all(np.isfinite(outs[nan_page]))
+    assert np.array_equal(outs[nan_page], outs[0])
+    np.testing.assert_array_equal(outs[0][0], 0.0)
+
+
+@pytest.mark.parametrize("variant", sorted(_GROUP_VARIANTS))
+def test_paged_live_span_is_exactly_the_live_pages(variant):
+    """The K/V index maps clamp each page step into [first, last] of
+    ``_live_span``: for every length, that span holds exactly the pages
+    the kernel's liveness test keeps, so each live page is fetched once
+    and a dead step repeats its neighbour's block index."""
+    from repro.kernels.paged_attention import _live_span
+    ps, m = 8, 6
+    window, chunk = (_GROUP_VARIANTS[variant].get(k) for k in ("window",
+                                                              "chunk"))
+    for length in range(m * ps + 1):
+        first, last = (int(x) for x in _live_span(
+            jnp.int32(length), page_size=ps, window=window, chunk=chunk,
+            last_step=m - 1))
+        q_pos = length - 1
+        live = [i for i in range(m)
+                if i * ps < length
+                and (window is None or i * ps + ps - 1 > q_pos - window)
+                and (chunk is None or i * ps + ps - 1 >= (q_pos // chunk)
+                     * chunk)]
+        if live:
+            assert live == list(range(first, last + 1)), length
+        else:
+            assert (first, last) == (0, 0), length
+
+
 def test_decode_hbm_bytes_accounting():
     """The analytic byte counter mirrors the grid: full-length rows pay
     all pages, masks drop dead pages, int8 pays quantized width + scale

@@ -61,11 +61,14 @@ def _paged(q, k, v, bt, ln, *scales, **kw):
                            prefetch=decode_prefetch(bt, ln), **kw)
 
 
-# (q heads, kv heads, head dim, page dtype, v_dim): olmo-1b MHA, a GQA
-# group of 4, int8 pages, and minicpm3's absorbed-MLA latent pages
-# (one kv head of kv_lora + d_rope = 288, v = the leading 256 features)
+# (q heads, kv heads, head dim, page dtype, v_dim): olmo-1b MHA,
+# codeqwen1.5-7b MHA (32 kv heads of 128, the widest page block of any
+# configuration: 1 MiB of K and V a grid step), a GQA group of 4, int8
+# pages, and minicpm3's absorbed-MLA latent pages (one kv head of
+# kv_lora + d_rope = 288, v = the leading 256 features)
 PAGED_CASES = {
     "olmo1b_bf16": (16, 16, 128, BF16, None),
+    "codeqwen15_7b": (32, 32, 128, BF16, None),
     "gqa_g4": (32, 8, 128, BF16, None),
     "int8_pages": (16, 16, 128, I8, None),
     "mla_vdim": (40, 1, 288, BF16, 256),
@@ -97,6 +100,19 @@ def test_stacked_paged_attention_compiles_for_v5e(one_chip, case):
 
     def fn(q, k, v, bt, ln, layer, *scales):
         return _paged(q, k, v, bt, ln, *scales, v_dim=v_dim, layer=layer)
+    assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
+
+
+def test_stacked_paged_attention_compiles_at_served_shape(one_chip):
+    """olmo-1b's kernel at the shape the decode step serves it: batch
+    32, block tables 32 pages wide, the whole 16-layer stacked pool."""
+    q_heads, hd = 16, OLMO_POOL[-1]
+    b, m = 32, 32
+    shapes = [((b, q_heads, hd), BF16), (OLMO_POOL, BF16), (OLMO_POOL, BF16),
+              ((b, m), I32), ((b,), I32), ((), I32)]
+
+    def fn(q, k, v, bt, ln, layer):
+        return _paged(q, k, v, bt, ln, layer=layer)
     assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
 
 
