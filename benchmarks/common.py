@@ -6,11 +6,9 @@ exercise — no parallel implementations.
 from __future__ import annotations
 
 import dataclasses
-import json
 import os
-import sys
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -134,77 +132,6 @@ def eval_zoo(state) -> Dict[str, Any]:
     }
 
 
-#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
-#: TPU v5e ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
-#: 197 TFLOP/s bf16, 819 GB/s HBM.
-DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
-    "TPU v5 lite": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
-}
-
-
-def device_peaks(device_kind: str) -> Dict[str, float]:
-    """Peak FLOP/s and HBM bytes/s of one chip; a device without a
-    published entry is an error, never a default."""
-    try:
-        return DEVICE_PEAKS[device_kind]
-    except KeyError:
-        raise ValueError(f"no published peaks for device kind "
-                         f"{device_kind!r}: add them to "
-                         f"benchmarks.common.DEVICE_PEAKS with their "
-                         f"source") from None
-
-
 def emit(name: str, us_per_call: float, derived: str):
     """The scaffold's CSV contract: name,us_per_call,derived."""
     print(f"{name},{us_per_call:.1f},{derived}")
-
-
-def emit_json(name: str, payload: Dict[str, Any], out_dir: str = "results"
-              ) -> str:
-    """Machine-readable sibling of emit(): results/BENCH_<name>.json."""
-    os.makedirs(out_dir, exist_ok=True)
-    path = os.path.join(out_dir, f"BENCH_{name}.json")
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=2, sort_keys=True, default=float)
-    print(f"# wrote {path}")
-    return path
-
-
-def trace_dest(bench: str) -> Optional[str]:
-    """Where this benchmark writes its Chrome trace, or None (untraced).
-
-    ``--trace out.json`` on the benchmark's own command line wins;
-    otherwise ``REPRO_TRACE_DIR`` (set by ``benchmarks.run --trace-dir``)
-    maps to ``<dir>/<bench>.trace.json``.
-    """
-    argv = sys.argv[1:]
-    for i, a in enumerate(argv):
-        if a == "--trace" and i + 1 < len(argv):
-            return argv[i + 1]
-        if a.startswith("--trace="):
-            return a.split("=", 1)[1]
-    d = os.environ.get("REPRO_TRACE_DIR")
-    if d:
-        os.makedirs(d, exist_ok=True)
-        return os.path.join(d, f"{bench}.trace.json")
-    return None
-
-
-def tag_trace(path: Optional[str], tag: str) -> Optional[str]:
-    """foo.json + 'disagg' -> foo.disagg.json — per-mode trace files for
-    benchmarks that serve the same trace through two configurations."""
-    if path is None:
-        return None
-    root, ext = os.path.splitext(path)
-    return f"{root}.{tag}{ext or '.json'}"
-
-
-def export_trace(tracer, path: Optional[str]) -> None:
-    """Export + schema-check a benchmark's trace (no-op when untraced)."""
-    if tracer is None or path is None:
-        return
-    from repro.serving.observability import validate_chrome_trace
-    payload = tracer.export(path)
-    problems = validate_chrome_trace(payload)
-    assert not problems, f"invalid chrome trace {path}: {problems[:3]}"
-    print(f"# wrote {path} ({len(payload['traceEvents'])} events)")

@@ -42,29 +42,26 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
                              chunk=chunk, scale=scale, logit_cap=logit_cap)
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
+def paged_attention(q, k_pages, v_pages, prefetch, layer, *,
                     window: Optional[int] = None,
                     chunk: Optional[int] = None,
                     logit_cap: Optional[float] = None,
                     scale: Optional[float] = None,
                     k_scales=None, v_scales=None,
-                    v_dim: Optional[int] = None,
-                    grouped: bool = True,
-                    prefetch=None,
-                    layer=None):
-    """Paged decode attention through the Pallas kernel (KV-head-grouped
-    grid, block-table scalar prefetch, int8 dequant in-kernel), for
-    callers that checked ``use_pallas()``; their jnp path is the gather
-    in attention.paged_decode_attention.  q: (B, H, hd) one token per
-    row; lengths: (B,).  ``prefetch`` is the combined (B, M+1) operand
-    from :func:`repro.kernels.paged_attention.decode_prefetch`, built
-    once per decode step and shared across layers.  ``layer`` selects
-    the layer of a stacked (L, P, K, ps, hd) pool."""
-    return _paged_pallas(q, k_pages, v_pages, block_tables, lengths,
+                    v_dim: Optional[int] = None):
+    """Paged decode attention through the Pallas kernel (one grid step
+    per (row, page) covering every kv head, block table and lengths
+    scalar-prefetched, int8 dequant in-kernel), for callers that
+    checked ``use_pallas()``; their jnp path is the gather in
+    attention.paged_decode_attention.  q: (B, H, hd) one token per row;
+    k_pages/v_pages: the stacked (L, P, K, ps, hd) pool read at
+    ``layer``; ``prefetch``: the (B, M+1) operand from
+    :func:`repro.kernels.paged_attention.decode_prefetch`, built once
+    per decode step and shared across layers."""
+    return _paged_pallas(q, k_pages, v_pages, prefetch, layer,
                          window=window, chunk=chunk, logit_cap=logit_cap,
                          scale=scale, k_scales=k_scales, v_scales=v_scales,
-                         v_dim=v_dim, grouped=grouped, prefetch=prefetch,
-                         layer=layer, interpret=_interpret())
+                         v_dim=v_dim, interpret=_interpret())
 
 
 def selective_scan(x, dt, b_mat, c_mat, a_mat, d_vec):

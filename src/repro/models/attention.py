@@ -678,17 +678,20 @@ def paged_decode_attention(q, cache: Params, block_tables, pos, *,
     per-row query positions (each row's k/v already inserted); ``layer``
     the layer of a stacked pool to read.
     On TPU this lowers to the Pallas paged-attention kernel (block
-    table scalar-prefetched, pages gathered page-by-page); elsewhere it
-    runs the gather + shared-mask jnp path.  Returns (B, 1, H, vd).
+    table scalar-prefetched, pages gathered page-by-page), which reads
+    a stacked pool at ``layer`` and takes ``prefetch``
+    (:func:`build_decode_prefetch`), built here when the caller passes
+    none; elsewhere it runs the gather + shared-mask jnp path.
+    Returns (B, 1, H, vd).
     """
     from repro.kernels import ops as kops
     if kops.use_pallas():
-        lengths = jnp.asarray(pos, jnp.int32).reshape((-1,)) + 1
+        if prefetch is None:
+            prefetch = build_decode_prefetch(block_tables, pos)
         out = kops.paged_attention(
-            q[:, 0], cache["k"], cache["v"], block_tables, lengths,
+            q[:, 0], cache["k"], cache["v"], prefetch, layer,
             window=window, chunk=chunk, scale=scale, logit_cap=logit_cap,
-            k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
-            prefetch=prefetch, layer=layer)
+            k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"))
         return out[:, None]
     k, v = paged_gather_kv(cache, block_tables, layer)
     k = shard(k, "batch", None, "kv_heads", None)

@@ -17,7 +17,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from repro.models.attention import (blocked_attention, cache_insert,
+from repro.models.attention import (blocked_attention,
+                                    build_decode_prefetch, cache_insert,
                                     cache_prefill, decode_attention,
                                     gather_pages, masked_causal_attention,
                                     masked_decode_attention,
@@ -175,11 +176,12 @@ def mla_decode(params: Params, x, cache: Params, pos, *, num_heads: int,
         if kops.use_pallas():
             # v rides as the leading kv_lora features of the same
             # latent slab (v_dim), so the kernel DMAs each page once
-            lengths = jnp.asarray(pos, jnp.int32).reshape((-1,)) + 1
+            if prefetch is None:
+                prefetch = build_decode_prefetch(block_tables, pos)
             out_lat = kops.paged_attention(
-                q_cat[:, 0], cache["k"], cache["k"], block_tables, lengths,
+                q_cat[:, 0], cache["k"], cache["k"], prefetch, layer,
                 scale=1.0 / math.sqrt(d_nope + d_rope),
-                v_dim=kv_lora, prefetch=prefetch, layer=layer)[:, None]
+                v_dim=kv_lora)[:, None]
         else:
             lat = gather_pages(cache["k"], block_tables, layer)  # (B,T,1,L)
             out_lat = masked_decode_attention(

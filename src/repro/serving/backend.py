@@ -1103,7 +1103,7 @@ class BackendServer:
             # the cluster heartbeat: capacity rides the reply envelope;
             # the body gossips load, the prefix-chunk digest the router
             # scores placement against, and the prefill-work counters
-            # bench_cluster sums into aggregate prefill cost per policy
+            # the router reports per host
             st = self.inner.stats()
             return {"queue_depth": self.inner.capacity().inflight,
                     "seqs": len(self._seqs),

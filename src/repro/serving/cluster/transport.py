@@ -30,8 +30,7 @@ does:
   sweeps it in a loop, pushing each sweep's ``new_tokens`` rows as
   unsolicited ``push`` frames the moment they exist.  The client's
   ``decode_batch`` just waits for the next push — remote inter-token
-  latency tracks local ITL instead of adding a round trip per token
-  (bench_cluster asserts the ratio).
+  latency tracks local ITL instead of adding a round trip per token.
 * **Flow control.**  The push stream is credit-gated: the client acks
   each push (``push_ack``) after applying it, and the sweep loop stays
   at most ``stream_window`` pushes ahead.  A slow consumer throttles

@@ -530,33 +530,3 @@ class PagedSequence:
             return "stop"
         return "length"
 
-
-def pool_bytes_per_page(cfg, page_size: int, dtype=None) -> int:
-    """Device bytes one page costs across every layer of a model
-    (shape-only: computed via eval_shape, nothing is allocated)."""
-    import jax
-    from repro.models import transformer as tf
-    shapes = tf.abstract_caches(cfg, 0, 0, dtype, num_pages=1,
-                                page_size=page_size)
-    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
-               for x in jax.tree.leaves(shapes))
-
-
-def pool_bytes_per_token(cfg, page_size: int, dtype=None) -> int:
-    """Device bytes one resident token costs across every layer of a
-    model — pool_bytes_per_page / page_size.  This is the lower bound
-    on decode HBM reads per generated token for a full-attention stack
-    (every resident token's K/V is fetched once per step when the
-    kernel is KV-head-grouped); the roofline report compares the
-    kernel's measured bytes/token against it."""
-    return pool_bytes_per_page(cfg, page_size, dtype) // page_size
-
-
-def ring_cache_bytes(cfg, batch: int, max_len: int, dtype=None) -> int:
-    """Device bytes the ring-buffer engine reserves for ``batch``
-    slots of ``max_len`` tokens (the worst-case ceiling paging lifts)."""
-    import jax
-    from repro.models import transformer as tf
-    shapes = tf.abstract_caches(cfg, batch, max_len, dtype)
-    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
-               for x in jax.tree.leaves(shapes))

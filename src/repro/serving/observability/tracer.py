@@ -9,16 +9,16 @@ COW, OutOfPages requeue, logit-cache hit, prewarm) and backend
 compiles (``repro.compile``), and periodic gauge samples — all into
 one bounded lock-free ring buffer.  ``tracer.export(path)``
 renders the buffer as Chrome trace-event / Perfetto JSON with one
-track per backend executor and one per request, so bench_disagg's
-interleaved-vs-disagg ITL win is visible as a timeline.
+track per backend executor and one per request, so a disaggregated
+backend's prefill and decode executors show as separate timelines.
 
 Design constraints, in order:
 
 * **Disabled must be free.**  Hot paths hold a tracer reference and
   guard with ``if tracer.enabled:`` before taking timestamps; the
   :data:`NULL_TRACER` singleton makes every unguarded call a cheap
-  no-op.  Benchmarks assert token-identical outputs traced vs
-  untraced — instrumentation only reads clocks and appends to host
+  no-op.  tests/test_observability.py asserts token-identical
+  outputs traced vs untraced — instrumentation only reads clocks and appends to host
   buffers, it never touches RNG state or array shapes.
 * **Recording is lock-free.**  Events are plain tuples written into a
   preallocated ring; slot indices come from ``itertools.count()``,

@@ -35,7 +35,7 @@ Determinism contract: every mux bucket has the same static shape
 (max_batch_size), so each model runs exactly one compiled program and
 a request's output is bitwise-identical to ``reference_output`` — the
 same model step applied to that request alone in a padded bucket.
-benchmarks/bench_scheduler.py asserts this per request.
+tests/test_scheduler.py asserts this per request.
 """
 from __future__ import annotations
 

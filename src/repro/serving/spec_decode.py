@@ -19,7 +19,7 @@ Token-exactness is by construction, not sampling-trickery: rows only
 speculate at resolved temperature <= 0, verification takes the
 verifier's argmax after every fed position, and the committed stream
 is EXACTLY the token sequence plain greedy decode on the target alone
-would emit (benchmarks/bench_spec_decode.py asserts bitwise identity).
+would emit (tests/test_spec_decode.py asserts bitwise identity).
 Everything that breaks the happy path degrades to plain decode, never
 to wrong tokens:
 

@@ -183,6 +183,8 @@ def test_chunked_prefill_and_prefix_sharing_parity(model, kind):
         assert snap["prefill_tokens_shared"] >= 8
     if kind == "disagg":
         assert snap["transfers"] == 3
+    elif kind == "inproc":
+        assert snap["transfers"] == 0
 
 
 @pytest.mark.parametrize("kind", BACKENDS)

@@ -187,6 +187,93 @@ def test_prefix_aware_placement_routes_repeats():
     asyncio.run(main())
 
 
+def test_prefix_aware_placement_computes_fewer_prefill_tokens():
+    """The same repeated-prefix trace, one request at a time with a
+    probe after each, on two fresh hosts under prefix-aware and under
+    least-loaded placement: identical outputs, and strictly fewer
+    prefill tokens computed across the hosts when placement chases the
+    prefix."""
+    prefix = np.asarray(prompt_of(16, 11))
+    prompts = [np.concatenate([prefix, np.asarray([(17 + i) % 64,
+                                                   (29 + i) % 64],
+                                                  np.int32)])
+               for i in range(8)]
+
+    async def serve(prefix_aware):
+        servers, router = await start_cluster(host_tier_pages=32,
+                                              prefix_aware=prefix_aware)
+        sched = PagedLLMScheduler(backends=[router],
+                                  cfg=PagedLLMConfig(prefill_chunk_pages=1))
+        outs = []
+        async with sched:
+            for p in prompts:
+                outs.append(np.asarray(await sched.submit(
+                    p, SamplingParams(max_new_tokens=3))))
+                await router.probe_hosts()
+            st = router.stats()["cluster"]
+        for srv in servers:
+            await srv.close()
+        return outs, sum(h["prefill_tokens_computed"]
+                         for h in st["per_host"])
+
+    async def main():
+        return await serve(True), await serve(False)
+
+    (pa_outs, pa_tokens), (lb_outs, lb_tokens) = asyncio.run(main())
+    for a, b in zip(pa_outs, lb_outs):
+        np.testing.assert_array_equal(a, b)
+    assert pa_tokens < lb_tokens, (pa_tokens, lb_tokens)
+
+
+def test_serve_subprocess_host_is_token_identical_to_local():
+    """``python -m repro.serving.cluster.serve`` in its own process
+    prints ``LISTENING <port>`` and, reached over the socket, serves
+    bitwise the tokens of a local backend built with the same flags."""
+    import os
+    import signal
+    import subprocess
+    import sys
+
+    import repro.serving
+    src = os.path.abspath(os.path.join(
+        os.path.dirname(repro.serving.__file__), "..", ".."))
+    path = os.pathsep.join(filter(None, [src, os.environ.get("PYTHONPATH")]))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "repro.serving.cluster.serve", "--port", "0",
+         "--host-label", "sub0"],
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": path})
+    prompts = [prompt_of(9, f) for f in range(3)]
+    try:
+        line = proc.stdout.readline().strip()
+        assert line.startswith("LISTENING "), line
+        port = int(line.split()[1])
+
+        async def main():
+            louts = await run_local(prompts, 5)
+            client = SocketClientBackend("127.0.0.1", port, name="sock:sub0",
+                                         streaming=True, heartbeat_s=1.0)
+            sched = PagedLLMScheduler(
+                backends=[client], cfg=PagedLLMConfig(prefill_chunk_pages=1))
+            async with sched:
+                couts = [np.asarray(await sched.submit(
+                    p, SamplingParams(max_new_tokens=5))) for p in prompts]
+            return louts, couts
+
+        louts, couts = asyncio.run(main())
+        for lo, co in zip(louts, couts):
+            np.testing.assert_array_equal(lo, co)
+    finally:
+        proc.send_signal(signal.SIGTERM)
+        try:
+            proc.wait(timeout=30)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait()
+        proc.stdout.close()
+    assert proc.returncode == 0
+
+
 def test_load_shedding_overrides_prefix_affinity():
     """A hot prefix host does not absorb unbounded load: once its load
     passes shed_factor * (min + 1), placement falls back to

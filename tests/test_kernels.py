@@ -7,7 +7,8 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mux_score import mux_score
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (decode_prefetch,
+                                           paged_attention)
 from repro.kernels.selective_scan import selective_scan
 
 KEY = jax.random.key(0)
@@ -39,6 +40,20 @@ def test_flash_attention_sweep(b, s, t, h, k, hd, vd, window, chunk, cap,
                                np.asarray(want, np.float32), atol=tol)
 
 
+#: every paged kernel test reads layer LAYER of a LAYERS-deep stacked
+#: pool, the only pool form the kernel takes; the oracle reads
+#: ``stacked[LAYER]``
+LAYERS, LAYER = 2, 1
+
+
+def _paged(q, k_pages, v_pages, bt, lengths, layer=LAYER, **kw):
+    """The kernel (interpret mode) on a stacked pool, block table and
+    lengths packed into the one prefetch operand."""
+    return paged_attention(q, k_pages, v_pages, decode_prefetch(bt, lengths),
+                           jnp.asarray(layer, jnp.int32), interpret=True,
+                           **kw)
+
+
 @pytest.mark.parametrize(
     "b,h,k,hd,vd,pages,ps,m,window,chunk,cap",
     [
@@ -53,18 +68,19 @@ def test_paged_attention_sweep(b, h, k, hd, vd, pages, ps, m, window, chunk,
     lengths, block-table indirection, window/chunk masks."""
     kq, kk, kv, kt = jax.random.split(KEY, 4)
     q = jax.random.normal(kq, (b, h, hd))
-    k_pages = jax.random.normal(kk, (pages, k, ps, hd))
-    v_pages = jax.random.normal(kv, (pages, k, ps, vd))
+    k_pages = jax.random.normal(kk, (LAYERS, pages, k, ps, hd))
+    v_pages = jax.random.normal(kv, (LAYERS, pages, k, ps, vd))
     # each row gets m distinct pages drawn from 1..pages-1 (0 = scratch)
     perm = np.stack([np.random.RandomState(i).permutation(pages - 1)[:m] + 1
                      for i in range(b)])
     bt = jnp.asarray(perm, jnp.int32)
     lengths = jnp.asarray(
         np.random.RandomState(7).randint(1, m * ps + 1, size=(b,)), jnp.int32)
-    out = paged_attention(q, k_pages, v_pages, bt, lengths, window=window,
-                          chunk=chunk, logit_cap=cap, interpret=True)
-    want = ref.paged_attention_ref(q, k_pages, v_pages, bt, lengths,
-                                   window=window, chunk=chunk, logit_cap=cap)
+    out = _paged(q, k_pages, v_pages, bt, lengths, window=window,
+                 chunk=chunk, logit_cap=cap)
+    want = ref.paged_attention_ref(q, k_pages[LAYER], v_pages[LAYER], bt,
+                                   lengths, window=window, chunk=chunk,
+                                   logit_cap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
@@ -74,13 +90,12 @@ def test_paged_attention_v_dim_is_k_slice():
     b, h, hd, ps, m, pages, vdim = 2, 4, 24, 4, 3, 8, 16
     kq, kk = jax.random.split(KEY)
     q = jax.random.normal(kq, (b, h, hd))
-    k_pages = jax.random.normal(kk, (pages, 1, ps, hd))     # MQA latent
+    k_pages = jax.random.normal(kk, (LAYERS, pages, 1, ps, hd))  # MQA latent
     bt = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
     lengths = jnp.asarray([m * ps, 5], jnp.int32)
-    out = paged_attention(q, k_pages, k_pages, bt, lengths, v_dim=vdim,
-                          interpret=True)
-    want = ref.paged_attention_ref(q, k_pages, k_pages[..., :vdim], bt,
-                                   lengths)
+    out = _paged(q, k_pages, k_pages, bt, lengths, v_dim=vdim)
+    want = ref.paged_attention_ref(q, k_pages[LAYER],
+                                   k_pages[LAYER, ..., :vdim], bt, lengths)
     assert out.shape == (b, h, vdim)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
@@ -100,11 +115,11 @@ def test_paged_attention_int8_dequant_in_kernel():
     outs = {}
     for dt in (jnp.float32, jnp.int8):
         cache = init_paged_kv_cache(pages, ps, k, hd, dtype=dt)
-        cache = paged_cache_prefill(cache, kk, vv, bt, start=0)
-        outs[dt] = paged_attention(
-            q, cache["k"], cache["v"], bt, lengths,
-            k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
-            interpret=True)
+        cache = jax.tree.map(lambda x: jnp.stack([x] * LAYERS), cache)
+        cache = paged_cache_prefill(cache, kk, vv, bt, start=0, layer=LAYER)
+        outs[dt] = _paged(q, cache["k"], cache["v"], bt, lengths,
+                          k_scales=cache.get("k_scale"),
+                          v_scales=cache.get("v_scale"))
     np.testing.assert_allclose(np.asarray(outs[jnp.int8], np.float32),
                                np.asarray(outs[jnp.float32], np.float32),
                                atol=0.06)
@@ -112,21 +127,26 @@ def test_paged_attention_int8_dequant_in_kernel():
 
 def _quantize_pages(pages):
     """Per-(slot, head) symmetric int8 + bf16 scales, like the pool's:
-    pages ([L,] P, K, ps, hd) -> scales ([L,] P, K, 1, ps)."""
-    sc = np.abs(np.asarray(pages)).max(axis=-1) / 127.0 + 1e-8
-    qp = np.clip(np.round(np.asarray(pages) / sc[..., None]), -127, 127)
+    pages (L, P, K, ps, hd) -> scales (L, P, K, 1, ps)."""
+    sc = np.abs(np.asarray(pages, np.float32)).max(axis=-1) / 127.0 + 1e-8
+    qp = np.clip(np.round(np.asarray(pages, np.float32) / sc[..., None]),
+                 -127, 127)
     return (jnp.asarray(qp, jnp.int8),
             jnp.asarray(sc[..., None, :], jnp.bfloat16))
+
+
+def _dequant(x, sc):
+    """int8 pages times their (..., 1, ps) scale rows, in float32."""
+    return x.astype(jnp.float32) * jnp.swapaxes(sc, -1, -2).astype(jnp.float32)
 
 
 @pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("variant", ["bf16", "int8", "mla_vdim"])
 def test_paged_attention_stacked_pool_reads_layer(variant, layer):
-    """A stacked (L, P, K, ps, hd) pool read at ``layer`` (one more
+    """A stacked (L, P, K, ps, hd) pool read at ``layer`` (a traced
     scalar-prefetch operand) attends that layer's pages alone: the
-    oracle on pages[layer], with either scalar encoding, for bf16 pages,
-    int8 pages with their scale slabs, and MLA's v_dim slice."""
-    from repro.kernels.paged_attention import decode_prefetch
+    oracle on pages[layer], for bf16 pages, int8 pages with their scale
+    slabs, and MLA's v_dim slice."""
     n_layers, b, h, kk, hd, ps, m = 3, 3, 4, 2, 16, 8, 4
     v_dim = None
     if variant == "mla_vdim":
@@ -142,11 +162,8 @@ def test_paged_attention_stacked_pool_reads_layer(variant, layer):
     if variant == "int8":
         k_pages, ks = _quantize_pages(k_pages)
         v_pages, vs = _quantize_pages(v_pages)
-        def dequant(x, sc):
-            return (x.astype(jnp.float32)
-                    * jnp.swapaxes(sc, -1, -2).astype(jnp.float32))
-        k_want = dequant(k_pages[layer], ks[layer])
-        v_want = dequant(v_pages[layer], vs[layer])
+        k_want = _dequant(k_pages[layer], ks[layer])
+        v_want = _dequant(v_pages[layer], vs[layer])
     if v_dim is not None:
         v_want = v_want[..., :v_dim]
     perm = np.stack([np.random.RandomState(i).permutation(pages - 1)[:m] + 1
@@ -154,27 +171,9 @@ def test_paged_attention_stacked_pool_reads_layer(variant, layer):
     bt = jnp.asarray(perm, jnp.int32)
     lengths = jnp.asarray([5, 17, 32], jnp.int32)
     want = ref.paged_attention_ref(q, k_want, v_want, bt, lengths)
-    for pf in (None, decode_prefetch(bt, lengths)):
-        out = paged_attention(q, k_pages, v_pages, bt, lengths, k_scales=ks,
-                              v_scales=vs, v_dim=v_dim, prefetch=pf,
-                              layer=jnp.asarray(layer, jnp.int32),
-                              interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   atol=2e-5)
-
-
-def test_paged_attention_layer_matches_pool_rank():
-    """A stacked pool needs a layer index and a single layer's pool
-    takes none: a mismatch is refused, not read at the wrong layer."""
-    q = jnp.zeros((1, 2, 16))
-    bt = jnp.ones((1, 2), jnp.int32)
-    ln = jnp.ones((1,), jnp.int32)
-    stacked = jnp.zeros((2, 3, 2, 8, 16))
-    with pytest.raises(ValueError, match="layer"):
-        paged_attention(q, stacked, stacked, bt, ln, interpret=True)
-    with pytest.raises(ValueError, match="layer"):
-        paged_attention(q, stacked[0], stacked[0], bt, ln, layer=0,
-                        interpret=True)
+    out = _paged(q, k_pages, v_pages, bt, lengths, layer=layer, k_scales=ks,
+                 v_scales=vs, v_dim=v_dim)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
 _GROUP_VARIANTS = {
@@ -188,40 +187,39 @@ _GROUP_VARIANTS = {
 @pytest.mark.parametrize("qtag", ["bf16", "int8"])
 @pytest.mark.parametrize("variant", sorted(_GROUP_VARIANTS))
 @pytest.mark.parametrize("g", [1, 2, 4, 8])
-def test_paged_grouped_token_identical_to_per_head(g, variant, qtag):
-    """The GQA re-grid is a pure traffic optimisation: for every group
-    size x mask variant x page dtype, the grouped kernel's output is
-    TOKEN-IDENTICAL (bitwise) to the per-head baseline grid on a
-    mixed-length batch, and its analytic HBM bytes are exactly 1/g."""
+def test_paged_attention_group_sizes_match_oracle(g, variant, qtag):
+    """For every GQA group size x mask variant x page dtype, the
+    kernel's one (row, page) grid, which runs all K kv heads and their
+    g query heads in one step, matches the gather oracle on a
+    mixed-length batch."""
     b, h, hd, ps, m = 3, 8, 16, 8, 4
     kk = h // g
     pages = 1 + b * m
     kw = dict(_GROUP_VARIANTS[variant])
     kq, kp, kv = jax.random.split(jax.random.fold_in(KEY, g), 3)
-    q = jax.random.normal(kq, (b, h, hd), jnp.bfloat16)
-    k_pages = jax.random.normal(kp, (pages, kk, ps, hd), jnp.bfloat16)
+    q = jax.random.normal(kq, (b, h, hd))
+    shape = (LAYERS, pages, kk, ps, hd)
+    k_pages = jax.random.normal(kp, shape, jnp.bfloat16)
     v_pages = (k_pages if variant == "mla_vdim"
-               else jax.random.normal(kv, (pages, kk, ps, hd), jnp.bfloat16))
+               else jax.random.normal(kv, shape, jnp.bfloat16))
     ks = vs = None
+    k_want, v_want = k_pages[LAYER], v_pages[LAYER]
     if qtag == "int8":
         k_pages, ks = _quantize_pages(k_pages)
         v_pages, vs = (k_pages, ks) if variant == "mla_vdim" \
             else _quantize_pages(v_pages)
+        k_want = _dequant(k_pages[LAYER], ks[LAYER])
+        v_want = _dequant(v_pages[LAYER], vs[LAYER])
+    if "v_dim" in kw:
+        v_want = v_want[..., :kw["v_dim"]]
     bt = jnp.asarray(np.arange(1, pages).reshape(b, m), jnp.int32)
     lengths = jnp.asarray([5, 17, 32], jnp.int32)     # mixed-length batch
-    outs = {}
-    for grouped in (True, False):
-        outs[grouped] = paged_attention(
-            q, k_pages, v_pages, bt, lengths, k_scales=ks, v_scales=vs,
-            grouped=grouped, interpret=True, **kw)
-    assert np.array_equal(np.asarray(outs[True], np.float32),
-                          np.asarray(outs[False], np.float32))
-    from repro.kernels.paged_attention import decode_hbm_bytes
-    by = {gr: decode_hbm_bytes(k_pages, v_pages, bt, lengths, num_q_heads=h,
-                               grouped=gr, window=kw.get("window"),
-                               chunk=kw.get("chunk"), v_dim=kw.get("v_dim"))
-          for gr in (True, False)}
-    assert by[True] * g == by[False]
+    out = _paged(q, k_pages, v_pages, bt, lengths, k_scales=ks, v_scales=vs,
+                 **kw)
+    want = ref.paged_attention_ref(
+        q, k_want.astype(jnp.float32), v_want.astype(jnp.float32), bt,
+        lengths, window=kw.get("window"), chunk=kw.get("chunk"))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
 def test_paged_zero_length_rows_are_exact_zeros():
@@ -232,42 +230,18 @@ def test_paged_zero_length_rows_are_exact_zeros():
     pages = 1 + b * m
     kq, kp = jax.random.split(KEY)
     q = jax.random.normal(kq, (b, h, hd))
-    k_pages = jax.random.normal(kp, (pages, kk, ps, hd))
+    k_pages = jax.random.normal(kp, (LAYERS, pages, kk, ps, hd))
     bt = jnp.asarray(np.arange(1, pages).reshape(b, m), jnp.int32)
     lengths = jnp.asarray([0, 7, 0], jnp.int32)
-    for grouped in (True, False):
-        out = np.asarray(paged_attention(q, k_pages, k_pages, bt, lengths,
-                                         grouped=grouped, interpret=True))
-        assert np.all(np.isfinite(out))
-        np.testing.assert_array_equal(out[0], 0.0)
-        np.testing.assert_array_equal(out[2], 0.0)
-        assert np.abs(out[1]).max() > 0
-    want = np.asarray(ref.paged_attention_ref(q, k_pages, k_pages, bt,
-                                              lengths))
+    out = np.asarray(_paged(q, k_pages, k_pages, bt, lengths))
+    assert np.all(np.isfinite(out))
+    np.testing.assert_array_equal(out[0], 0.0)
+    np.testing.assert_array_equal(out[2], 0.0)
+    assert np.abs(out[1]).max() > 0
+    want = np.asarray(ref.paged_attention_ref(q, k_pages[LAYER],
+                                              k_pages[LAYER], bt, lengths))
     assert np.all(np.isfinite(want))
     np.testing.assert_array_equal(want[[0, 2]], 0.0)
-
-
-def test_paged_combined_prefetch_matches_separate_operands():
-    """decode_prefetch packs (bt, lengths) into one (B, M+1) operand;
-    the kernel must read identical liveness from either encoding."""
-    from repro.kernels.paged_attention import decode_prefetch
-    b, h, kk, hd, ps, m = 2, 8, 2, 16, 8, 4
-    pages = 1 + b * m
-    kq, kp, kv = jax.random.split(KEY, 3)
-    q = jax.random.normal(kq, (b, h, hd))
-    k_pages = jax.random.normal(kp, (pages, kk, ps, hd))
-    v_pages = jax.random.normal(kv, (pages, kk, ps, hd))
-    bt = jnp.asarray(np.arange(1, pages).reshape(b, m), jnp.int32)
-    lengths = jnp.asarray([13, 32], jnp.int32)
-    pf = decode_prefetch(bt, lengths)
-    assert pf.shape == (b, m + 1) and pf.dtype == jnp.int32
-    for kw in ({}, {"window": 9}, {"chunk": 16}):
-        sep = paged_attention(q, k_pages, v_pages, bt, lengths,
-                              interpret=True, **kw)
-        comb = paged_attention(q, k_pages, v_pages, bt, lengths,
-                               prefetch=pf, interpret=True, **kw)
-        assert np.array_equal(np.asarray(sep), np.asarray(comb))
 
 
 @pytest.mark.parametrize("variant", ["full", "window"])
@@ -277,7 +251,6 @@ def test_paged_dead_pages_never_reach_the_output(g, variant):
     before its window, every entry of a length-0 row) name pages filled
     with NaN: the output is finite and bitwise equal to the same call
     with those entries naming a page of zeros."""
-    from repro.kernels.paged_attention import decode_prefetch
     b, kk, hd, ps, m = 4, 2, 16, 8, 5
     h = kk * g
     kw = _GROUP_VARIANTS[variant]
@@ -287,11 +260,11 @@ def test_paged_dead_pages_never_reach_the_output(g, variant):
     kq, kp, kv = jax.random.split(jax.random.fold_in(KEY, g), 3)
     q = jax.random.normal(kq, (b, h, hd), jnp.bfloat16)
     # page 0 holds zeros, the last page NaN, the rest random
-    shape = (nan_page + 1, kk, ps, hd)
+    shape = (LAYERS, nan_page + 1, kk, ps, hd)
     k_pages = jax.random.normal(kp, shape, jnp.bfloat16)
     v_pages = jax.random.normal(kv, shape, jnp.bfloat16)
-    k_pages = k_pages.at[0].set(0).at[-1].set(jnp.nan)
-    v_pages = v_pages.at[0].set(0).at[-1].set(jnp.nan)
+    k_pages = k_pages.at[:, 0].set(0).at[:, -1].set(jnp.nan)
+    v_pages = v_pages.at[:, 0].set(0).at[:, -1].set(jnp.nan)
     bt = np.arange(1, nan_page).reshape(b, m)
     dead = np.zeros((b, m), bool)
     for r, length in enumerate(lengths.tolist()):
@@ -304,10 +277,9 @@ def test_paged_dead_pages_never_reach_the_output(g, variant):
     outs = {}
     for fill in (0, nan_page):
         tab = jnp.asarray(np.where(dead, fill, bt), jnp.int32)
-        ln = jnp.asarray(lengths)
-        outs[fill] = np.asarray(paged_attention(
-            q, k_pages, v_pages, tab, ln, prefetch=decode_prefetch(tab, ln),
-            interpret=True, **kw), np.float32)
+        outs[fill] = np.asarray(_paged(q, k_pages, v_pages, tab,
+                                       jnp.asarray(lengths), **kw),
+                                np.float32)
     assert np.all(np.isfinite(outs[nan_page]))
     assert np.array_equal(outs[nan_page], outs[0])
     np.testing.assert_array_equal(outs[0][0], 0.0)
@@ -339,32 +311,68 @@ def test_paged_live_span_is_exactly_the_live_pages(variant):
             assert (first, last) == (0, 0), length
 
 
-def test_decode_hbm_bytes_accounting():
-    """The analytic byte counter mirrors the grid: full-length rows pay
-    all pages, masks drop dead pages, int8 pays quantized width + scale
-    slabs, and grouped/per-head differ by exactly g."""
-    from repro.kernels.paged_attention import decode_hbm_bytes
-    ps, kk, hd, m = 8, 2, 16, 4
-    h = 8
-    k_pages = jnp.zeros((9, kk, ps, hd), jnp.float32)
-    bt = np.arange(1, 9).reshape(2, m)
-    full = decode_hbm_bytes(k_pages, k_pages, bt, [32, 32], num_q_heads=h)
-    # 2 rows x 4 live pages x 2 kv heads x (ps*hd*4 k + ps*hd*4 v)
-    assert full == 2 * 4 * kk * (ps * hd * 4 * 2)
-    short = decode_hbm_bytes(k_pages, k_pages, bt, [32, 1], num_q_heads=h)
-    assert short == full // 8 * 5            # row 1 touches 1 of 4 pages
-    win = decode_hbm_bytes(k_pages, k_pages, bt, [32, 32], num_q_heads=h,
-                           window=4)
-    assert win < full                        # only the trailing page lives
-    per_head = decode_hbm_bytes(k_pages, k_pages, bt, [32, 32],
-                                num_q_heads=h, grouped=False)
-    assert per_head == full * (h // kk)
-    q8 = jnp.zeros((9, kk, ps, hd), jnp.int8)
-    quant = decode_hbm_bytes(q8, q8, bt, [32, 32], num_q_heads=h)
-    assert quant == 2 * 4 * kk * (ps * hd * 1 * 2 + 2 * ps * 2)
-    vd = decode_hbm_bytes(k_pages, k_pages, bt, [32, 32], num_q_heads=h,
-                          v_dim=hd // 2)
-    assert vd == 2 * 4 * kk * (ps * hd * 4 + ps * (hd // 2) * 4)
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_paged_decode_attention_kernel_builds_its_prefetch(monkeypatch,
+                                                           kv_dtype):
+    """``paged_decode_attention`` called with no prefetch operand builds
+    it itself when it takes the kernel path, and matches its own jnp
+    gather path on the same stacked pool, for bf16 and int8 pages."""
+    from repro.kernels import ops as kops
+    from repro.models import attention as attn
+    b, h, kk, hd, ps, m = 3, 4, 2, 16, 4, 3
+    pages = 1 + b * m
+    ks = jax.random.split(jax.random.fold_in(KEY, 5), 3)
+    cache = attn.init_paged_kv_cache(pages, ps, kk, hd, dtype=kv_dtype)
+    cache = jax.tree.map(lambda x: jnp.stack([x] * LAYERS), cache)
+    bt = jnp.asarray(np.arange(1, pages).reshape(b, m), jnp.int32)
+    cache = attn.paged_cache_prefill(
+        cache, jax.random.normal(ks[0], (b, m * ps, kk, hd)),
+        jax.random.normal(ks[1], (b, m * ps, kk, hd)), bt, start=0,
+        layer=LAYER)
+    q = jax.random.normal(ks[2], (b, 1, h, hd))
+    pos = jnp.asarray([m * ps - 1, 5, 0], jnp.int32)
+    want = attn.paged_decode_attention(q, cache, bt, pos, layer=LAYER)
+    monkeypatch.setattr(kops, "_FORCE", True)
+    out = attn.paged_decode_attention(q, cache, bt, pos, layer=LAYER)
+    assert out.shape == want.shape == (b, 1, h, hd)
+    # the gather path dequantizes int8 pages into bf16 before its
+    # matmuls; the kernel applies the scales in f32 after them
+    tol = 2e-5 if kv_dtype == "bfloat16" else 2e-2
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=tol)
+
+
+def test_mla_decode_latent_pages_through_kernel(monkeypatch):
+    """Absorbed-MLA decode over a paged latent pool: the kernel path
+    (v the latent's leading kv_lora features, prefetch built inside
+    ``mla_decode``) matches the gather path, output and written pool."""
+    from repro.kernels import ops as kops
+    from repro.models import attention as attn
+    from repro.models import mla
+    b, d_model, h, kv_lora, d_nope, d_rope, ps, m = 3, 32, 2, 16, 8, 8, 4, 3
+    dims = dict(num_heads=h, kv_lora=kv_lora, d_nope=d_nope, d_rope=d_rope,
+                v_head_dim=8, rope_theta=10000.0)
+    pages = 1 + b * m
+    ks = jax.random.split(jax.random.fold_in(KEY, 6), 3)
+    params = mla.init_mla(ks[0], d_model=d_model, q_lora=16,
+                          **{k: v for k, v in dims.items()
+                             if k != "rope_theta"})
+    cache = attn.init_paged_kv_cache(pages, ps, 1, kv_lora + d_rope,
+                                     v_head_dim=1, dtype=jnp.float32)
+    cache = jax.tree.map(lambda x: jnp.stack([x] * LAYERS), cache)
+    bt = jnp.asarray(np.arange(1, pages).reshape(b, m), jnp.int32)
+    lat = jax.random.normal(ks[1], (b, m * ps, 1, kv_lora + d_rope))
+    cache = attn.paged_cache_prefill(cache, lat, lat[..., :1], bt, start=0,
+                                     layer=LAYER)
+    x = jax.random.normal(ks[2], (b, 1, d_model))
+    pos = jnp.asarray([m * ps - 1, 6, 0], jnp.int32)
+    want, want_cache = mla.mla_decode(params, x, cache, pos, block_tables=bt,
+                                      layer=LAYER, **dims)
+    monkeypatch.setattr(kops, "_FORCE", True)
+    out, out_cache = mla.mla_decode(params, x, cache, pos, block_tables=bt,
+                                    layer=LAYER, **dims)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out_cache["k"]),
+                                  np.asarray(want_cache["k"]))
 
 
 @pytest.mark.parametrize("b,s,d,n,chunk,bd", [

@@ -98,6 +98,30 @@ def test_spill_restore_parity_on_vs_off(variant):
     assert eng.pool.pages_in_use == 0 and eng.pool.prefix_entries == 0
 
 
+def test_repeated_host_hits_stay_token_identical():
+    """Each repeat of a prompt whose prefix went cold since its last run
+    restores it from the host tier: every repeat is one hit restoring
+    all three retained pages, and every output is bitwise the tier-less
+    engine's."""
+    cfg = tiny_config("full")
+    params = tf.init_params(cfg, jax.random.key(3))
+    pa, _ = prompts_with_shared_prefix(cfg)
+    ref = make_flat_engine(cfg, params).generate_paged(
+        pa, max_new_tokens=6)["tokens"]
+    eng = make_tiered_engine(cfg, params)
+    np.testing.assert_array_equal(
+        eng.generate_paged(pa, max_new_tokens=6)["tokens"], ref)
+    trials = 3
+    for _ in range(trials):
+        eng.pool.drop_retained()                # the prefix goes to host
+        np.testing.assert_array_equal(
+            eng.generate_paged(pa, max_new_tokens=6)["tokens"], ref)
+    ht = eng.host_tier.stats()
+    assert ht["hits"] == trials and ht["restored_pages"] == 3 * trials
+    eng.pool.drop_retained()
+    assert eng.pool.pages_in_use == 0
+
+
 def test_spill_and_restore_spans_lie_on_the_tracer_clock():
     """The spill and restore spans take their times from the tracer's
     clock, so they fall inside the run's own interval on that clock."""
@@ -178,8 +202,10 @@ def test_demand_spill_completes_would_reject_alloc():
     with pytest.raises(OutOfPages):
         make_flat_engine(cfg, params, num_pages=6).prefill_into_pages(
             pc, max_new_tokens=6)
+    need, _ = eng.admission_page_cost(pc, 6)
+    assert need == 6
     seq = eng.prefill_into_pages(pc, max_new_tokens=6)   # spills, admits
-    assert eng.pool.stats()["pages_spilled"] >= 1
+    assert eng.pool.stats()["pages_spilled"] >= need - 5
     eng.pool.release(seq)
     # and the spilled prefix is not lost: A's next admission restores
     eng.pool.drop_retained()

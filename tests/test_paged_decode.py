@@ -447,7 +447,8 @@ def test_scheduler_continuous_decode_trace():
     assert snap["completed"] == 4 and snap["failed"] == 0
     assert snap["mixed_admission_batches"] >= 1
     assert snap["pools"][0]["pages_in_use"] == 0
-    assert snap["pools"][0]["peak_pages_in_use"] > 0
+    assert 0 < snap["pools"][0]["peak_pages_in_use"] < \
+        snap["pools"][0]["num_pages"]
     assert snap["tokens_generated"] >= 4 * 10 - 4   # first tokens from prefill
 
 

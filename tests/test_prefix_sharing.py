@@ -299,3 +299,52 @@ def test_scheduler_admission_budgets_unique_pages():
     assert snap["prefill_tokens_shared"] == 8    # pb mapped the prefix
     assert snap["pools"][0]["peak_pages_in_use"] == 6   # 4 + 2 unique
     assert snap["pools"][0]["pages_in_use"] == 0
+
+
+def test_shared_prompt_trace_prefills_prefix_once():
+    """A system-prompt trace through the scheduler — three requests
+    opening with the same 3-page prefix plus a duplicate of the first,
+    one decode slot each — served with sharing off and on: identical
+    outputs; with sharing the
+    prefix is prefilled exactly once (every follower maps all of it,
+    the duplicate all but its final token), strictly fewer unique pages
+    are held, and every copy-on-write is fused into a decode step."""
+    from repro.serving.observability.tracer import INSTANT, Tracer
+    cfg = tiny_config("gqa_mixed")
+    params = tf.init_params(cfg, jax.random.key(8))
+    prefix_len, tails = 3 * PS, (3, 6, 2)
+    prompts = prompts_with_shared_prefix(cfg, prefix_len=prefix_len,
+                                         tails=tails, seed=23)
+    prompts.append(prompts[0].copy())
+
+    def serve(sharing):
+        eng = make_engine(cfg, params, sharing=sharing)
+        tracer = Tracer()
+        sched = PagedLLMScheduler([eng], PagedLLMConfig(max_new_tokens=5),
+                                  tracer=tracer)
+
+        async def main():
+            async with sched:
+                return await asyncio.gather(*(sched.submit(p)
+                                              for p in prompts))
+
+        outs = asyncio.run(main())
+        snap = sched.snapshot()
+        assert snap["completed"] == len(prompts) and snap["failed"] == 0
+        assert snap["pools"][0]["pages_in_use"] == 0
+        return outs, snap, tracer.events()
+
+    base, base_snap, _ = serve(False)
+    shared, snap, evs = serve(True)
+    for a, b in zip(base, shared):
+        np.testing.assert_array_equal(a, b)
+    assert base_snap["prefill_tokens_shared"] == 0
+    assert snap["prefill_tokens_shared"] == \
+        (len(tails) - 1) * prefix_len + len(prompts[0]) - 1
+    assert (snap["pools"][0]["peak_pages_in_use"]
+            < base_snap["pools"][0]["peak_pages_in_use"])
+    cows = [e for e in evs if e[2] == "cow" and e[1] == INSTANT]
+    assert snap["cow_copies"] >= 1 and len(cows) == snap["cow_copies"]
+    assert all(e[6]["fused"] is True for e in cows)
+    assert not [e for e in evs if "copy_page" in e[2]
+                or "copy_page" in str((e[6] or {}).get("op", ""))]

@@ -494,3 +494,55 @@ def test_open_loop_replay_respects_schedule():
     snap = asyncio.run(main())
     assert snap["completed"] == 10
     assert snap["slo_violations"] == 0   # 100ms default SLO, light load
+
+
+@pytest.mark.parametrize("pattern,rate", [("poisson", 400.0),
+                                          ("bursty", 200.0)])
+def test_real_mux_server_outputs_bitwise_match_direct_call(pattern, rate):
+    """A random-init three-model CNN zoo behind a real MuxServer, fed
+    an open-loop schedule: every output is bitwise the selected model
+    called directly on that request alone, at the scheduler's bucket
+    shape (the only shape at which row results are stable)."""
+    import jax
+
+    from repro.core.multiplexer import init_image_backbone, init_mux
+    from repro.models.cnn import ZOO_SPECS, cnn_forward, init_zoo, zoo_costs
+    from repro.serving.mux_server import MuxServer, MuxServerConfig
+
+    zoo_names, image_size = ("zoo_xxs", "zoo_xs", "zoo_s"), 16
+    zoo = init_zoo(jax.random.key(0), num_classes=10, names=zoo_names)
+    costs = zoo_costs(zoo_names, image_size=image_size)
+    mux = init_mux(jax.random.key(1),
+                   backbone=init_image_backbone(jax.random.key(2),
+                                                meta_dim=32),
+                   model_names=list(zoo_names), costs=costs, meta_dim=32,
+                   proj_dim=16)
+
+    def make_fn(n):
+        cps = ZOO_SPECS[n].get("convs_per_stage", 1)
+        return lambda xs: cnn_forward(zoo[n], xs, convs_per_stage=cps)[0]
+
+    server = MuxServer(mux, [make_fn(n) for n in zoo_names],
+                       [costs[n] for n in zoo_names], MuxServerConfig())
+    traffic = TrafficConfig(rate=rate, num_requests=32, pattern=pattern,
+                            seed=0)
+    xs = np.asarray(jax.random.normal(
+        jax.random.key(3), (traffic.num_requests, image_size, image_size,
+                            3)))
+
+    async def main():
+        sched = MuxScheduler(server, SchedulerConfig(
+            max_batch_size=8, max_wait_ms=4.0, default_slo_ms=250.0))
+        sched.warmup(xs[0])
+        async with sched:
+            futures = await replay(sched.submit, list(xs),
+                                   arrival_times(traffic))
+            return sched, await asyncio.gather(*futures)
+
+    sched, outs = asyncio.run(main())
+    for i, out in enumerate(outs):
+        m = sched.reference_assignment(xs[i])
+        assert np.array_equal(np.asarray(out),
+                              sched.reference_output(xs[i], m)), i
+    snap = sched.metrics.snapshot()
+    assert snap["completed"] == traffic.num_requests and snap["failed"] == 0

@@ -172,3 +172,72 @@ def test_k0_degenerate_plain_decode():
     assert stats["spec_fallbacks"] == 0
     assert stats["draft_pool"]["peak_pages_in_use"] == 0
     assert_drained(spec)
+
+
+def _surgery_target(dcfg, dparams, layers):
+    """A deeper target that computes bitwise the drafter's logits: the
+    drafter's layers, then extra layers whose attention and MLP output
+    projections are zero, so they add nothing to the residual stream."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    tcfg = dataclasses.replace(dcfg, name=dcfg.name + "-target",
+                               num_layers=layers)
+    tp = tf.init_params(tcfg, jax.random.key(1))
+    blocks = {}
+    for name, tblk in tp["blocks"].items():
+        dblk = dparams["blocks"][name]
+        tail = jax.tree.map(lambda t, d: t[d.shape[0]:], tblk, dblk)
+        tail["attn"]["wo"] = jnp.zeros_like(tail["attn"]["wo"])
+        tail["mlp"]["down"] = jnp.zeros_like(tail["mlp"]["down"])
+        blocks[name] = jax.tree.map(
+            lambda d, t: jnp.concatenate([d, t], axis=0), dblk, tail)
+    return tcfg, {**{k: v for k, v in dparams.items() if k != "blocks"},
+                  "blocks": blocks}
+
+
+def test_mixed_draft_lengths_through_scheduler():
+    """The scheduler drives a SpeculativeBackend over a deeper target
+    that computes the drafter's logits exactly; short prompts draft
+    DRAFT_K tokens ahead, long ones are routed to plain decode (k=0).
+    Outputs are bitwise the plain target's through the same scheduler,
+    drafts happen, none fall back, and nearly every draft is accepted
+    (a float-ULP argmax tie between the one-token and multi-token step
+    shapes may reject one; the verifier's pick is then committed)."""
+    from repro.serving.scheduler import PagedLLMConfig, PagedLLMScheduler
+    dcfg = tiny_config("full")
+    dparams = tf.init_params(dcfg, jax.random.key(0))
+    tcfg, tparams = _surgery_target(dcfg, dparams, 2 * dcfg.num_layers)
+    prompts = [np.asarray(jax.random.randint(jax.random.key(i), (n,), 0,
+                                             tcfg.vocab_size))
+               for i, n in enumerate((7, 9, 8, 20))]
+
+    def k_fn(prompt):
+        return 0 if len(prompt) >= 16 else DRAFT_K
+
+    def serve(backend):
+        sched = PagedLLMScheduler(
+            backends=[backend], cfg=PagedLLMConfig(max_new_tokens=MAX_NEW))
+
+        async def main():
+            async with sched:
+                return await asyncio.gather(*(sched.submit(p)
+                                              for p in prompts))
+
+        outs = asyncio.run(main())
+        snap = sched.snapshot()
+        assert snap["completed"] == len(prompts) and snap["failed"] == 0
+        return outs, snap
+
+    plain, plain_snap = serve(InProcessBackend(build_engine(tcfg, tparams)))
+    draft = build_engine(dcfg, dparams, lazy=True, max_len=MAX_LEN + 16,
+                         pages=80)
+    spec = SpeculativeBackend(InProcessBackend(build_engine(tcfg, tparams)),
+                              draft, draft_k=DRAFT_K, k_fn=k_fn)
+    outs, snap = serve(spec)
+    for a, b in zip(plain, outs):
+        np.testing.assert_array_equal(a, b)
+    assert plain_snap["draft_tokens"] == 0
+    assert snap["draft_tokens"] > 0 and snap["spec_fallbacks"] == 0
+    assert snap["accepted_tokens"] >= 0.95 * snap["draft_tokens"]
+    assert_drained(spec)

@@ -259,6 +259,8 @@ def test_chunked_prefill_interleaves_with_decode(model):
         return sched, out_s, out_l, evs_s, evs_l
 
     sched, out_s, out_l, evs_s, evs_l = asyncio.run(main())
+    for evs in (evs_s, evs_l):
+        assert sum(e.type is EventType.FIRST_TOKEN for e in evs) == 1
     np.testing.assert_array_equal(out_l, ref_long)
     np.testing.assert_array_equal(out_s, ref_short)
     # 40 tokens at 8-token chunks: >= 4 prefill-progress events

@@ -55,10 +55,10 @@ def _compiled_text(fn, shapes, sharding) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _paged(q, k, v, bt, ln, *scales, **kw):
+def _paged(q, k, v, bt, ln, layer, *scales, **kw):
     ks, vs = scales if scales else (None, None)
-    return paged_attention(q, k, v, bt, ln, k_scales=ks, v_scales=vs,
-                           prefetch=decode_prefetch(bt, ln), **kw)
+    return paged_attention(q, k, v, decode_prefetch(bt, ln), layer,
+                           k_scales=ks, v_scales=vs, **kw)
 
 
 # (q heads, kv heads, head dim, page dtype, v_dim): olmo-1b MHA,
@@ -75,31 +75,18 @@ PAGED_CASES = {
 }
 
 
+@pytest.mark.parametrize("layers", [1, OLMO_POOL[0]])
 @pytest.mark.parametrize("case", sorted(PAGED_CASES))
-def test_paged_attention_compiles_for_v5e(one_chip, case):
+def test_paged_attention_compiles_for_v5e(one_chip, case, layers):
+    """Each kernel reading one layer of a stacked pool of 1 or 16
+    layers, the layer index a traced scalar-prefetch operand."""
     h, kv, hd, dt, v_dim = PAGED_CASES[case]
-    shapes = [((B, h, hd), BF16), ((PAGES, kv, PS, hd), dt),
-              ((PAGES, kv, PS, hd), dt), ((B, M), I32), ((B,), I32)]
-    if dt == I8:
-        shapes += [((PAGES, kv, 1, PS), BF16)] * 2
-    fn = functools.partial(_paged, v_dim=v_dim)
-    assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
-
-
-@pytest.mark.parametrize("case", sorted(PAGED_CASES))
-def test_stacked_paged_attention_compiles_for_v5e(one_chip, case):
-    """The same kernels reading one layer of a 16-layer stacked pool,
-    the layer index a traced scalar-prefetch operand."""
-    h, kv, hd, dt, v_dim = PAGED_CASES[case]
-    layers = OLMO_POOL[0]
     shapes = [((B, h, hd), BF16), ((layers, PAGES, kv, PS, hd), dt),
               ((layers, PAGES, kv, PS, hd), dt), ((B, M), I32), ((B,), I32),
               ((), I32)]
     if dt == I8:
         shapes += [((layers, PAGES, kv, 1, PS), BF16)] * 2
-
-    def fn(q, k, v, bt, ln, layer, *scales):
-        return _paged(q, k, v, bt, ln, *scales, v_dim=v_dim, layer=layer)
+    fn = functools.partial(_paged, v_dim=v_dim)
     assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
 
 
@@ -110,10 +97,7 @@ def test_stacked_paged_attention_compiles_at_served_shape(one_chip):
     b, m = 32, 32
     shapes = [((b, q_heads, hd), BF16), (OLMO_POOL, BF16), (OLMO_POOL, BF16),
               ((b, m), I32), ((b,), I32), ((), I32)]
-
-    def fn(q, k, v, bt, ln, layer):
-        return _paged(q, k, v, bt, ln, layer=layer)
-    assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
+    assert "tpu_custom_call" in _compiled_text(_paged, shapes, one_chip)
 
 
 def test_mux_score_compiles_for_v5e(one_chip):
